@@ -149,6 +149,9 @@ def bench_memhd_cell() -> None:
     env = dict(os.environ)
     env["PYTHONPATH"] = (os.path.join(root, "src")
                          + os.pathsep + env.get("PYTHONPATH", ""))
+    # A compile dry run by design: the child must never ask for the
+    # chip, which this process may already hold.
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, "-m", "benchmarks.hillclimb", "--memhd",
            "--dim", "256", "--columns", "256", "--samples", "8192"]
     t0 = time.perf_counter()

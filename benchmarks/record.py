@@ -26,6 +26,8 @@ per-metric keys or bumping ``SCHEMA_VERSION``:
       "created_unix": 1733...,
       "git_sha": "abc1234" | null,
       "jax_backend": "cpu" | "tpu" | ...,
+      "device": {"platform": "tpu", "device_kind": "TPU v5 lite",
+                 "count": 1},
       "jax_version": "0.4...",
       "meta": {...},                   # geometry / workload metadata
       "metrics": {
@@ -57,7 +59,7 @@ ENV_DIR = "MEMHD_BENCH_DIR"
 # The frozen schema: tests/test_bench_harness.py asserts these exactly.
 TOP_LEVEL_KEYS = frozenset({
     "schema_version", "bench", "created_unix", "git_sha",
-    "jax_backend", "jax_version", "meta", "metrics",
+    "jax_backend", "device", "jax_version", "meta", "metrics",
 })
 METRIC_REQUIRED_KEYS = frozenset({"us_per_call", "derived"})
 TIMING_KEYS = frozenset({
@@ -197,6 +199,8 @@ class Recorder:
 
     def record(self) -> Dict:
         import jax
+
+        from repro.obs import device_info
         meta = dict(self.meta)
         obs_meta = _obs_meta(self._obs_baseline)
         if obs_meta is not None:
@@ -207,6 +211,7 @@ class Recorder:
             "created_unix": int(time.time()),
             "git_sha": git_sha(),
             "jax_backend": jax.default_backend(),
+            "device": device_info(),
             "jax_version": jax.__version__,
             "meta": meta,
             "metrics": self.metrics,

@@ -113,6 +113,8 @@ def main(argv=None) -> None:
             print(f"{name}\t{module}")
         return
 
+    from repro import compile_cache
+    compile_cache.enable()
     print("name,us_per_call,derived")
     failures = []
     written = []

@@ -34,7 +34,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import qail
 from repro.core.types import EncoderConfig, MemhdConfig
 
-from repro.compat import shard_map as _shard_map
 
 Array = jax.Array
 
@@ -95,7 +94,7 @@ def make_epoch_fn(enc_cfg: EncoderConfig, am_cfg: MemhdConfig,
             return new_fp, miss
 
         from jax.sharding import PartitionSpec as P
-        new_fp, miss = _shard_map(
+        new_fp, miss = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(), P(), P(), P(), P(all_axes, None), P(all_axes)),
             out_specs=(P(), P()),
@@ -183,7 +182,7 @@ def make_scan_epoch_sharded(cfg: MemhdConfig, mesh, refresh_every: int = 1):
                 (jnp.arange(nb), hb_l, qb_l, yb_l, mb_l))
             return fp, binary, misses.sum()
 
-        fp, binary, n_miss = _shard_map(
+        fp, binary, n_miss = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(), P(), P(), P(None, all_axes, None),
                       P(None, all_axes, None), P(None, all_axes),

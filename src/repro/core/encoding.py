@@ -37,9 +37,13 @@ def init_projection(key: Array, cfg: EncoderConfig) -> EncoderParams:
 
 
 def encode_projection(params: EncoderParams, feats: Array) -> Array:
-    """H = M^T F, batched: (..., f) -> (..., D). Float accumulation."""
+    """H = M^T F, batched: (..., f) -> (..., D). Float accumulation at
+    float32 precision: the features are not bf16-exact, and the TPU's
+    default would round them (and so flip query bits that the fused
+    kernel and the CPU keep)."""
     m = params["projection"]
-    return jnp.einsum("...f,fd->...d", feats.astype(jnp.float32), m)
+    return jnp.einsum("...f,fd->...d", feats.astype(jnp.float32), m,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 # ---------------------------------------------------------------------------

@@ -32,7 +32,7 @@ Three implementations:
 """
 from __future__ import annotations
 
-from functools import partial
+from functools import cache, partial
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -44,9 +44,14 @@ from repro.core.types import MemhdConfig
 Array = jax.Array
 AmState = Dict[str, Array]
 
-# Buffer donation only helps (and only works) on accelerator backends;
-# on CPU it just emits "donation not usable" warnings.
-_DONATE = (0,) if jax.default_backend() in ("tpu", "gpu") else ()
+
+def _donate() -> Tuple[int, ...]:
+    """Argnums of the scan epoch's donated AM state. Donation only helps
+    (and only works) on accelerator backends; on CPU it just emits
+    "donation not usable" warnings. Asked at call time, so importing
+    this module does not initialise a backend."""
+    return (0,) if jax.default_backend() in ("tpu", "gpu") else ()
+
 
 # Incremented each time the scan-epoch body is *traced* (not executed).
 # The single-host-sync test asserts a multi-epoch fit traces it once.
@@ -256,19 +261,13 @@ def prebatch(h: Array, q: Array, labels: Array, batch_size: int,
             yb.reshape(nb, batch_size), mask.reshape(nb, batch_size))
 
 
-@partial(jax.jit,
-         static_argnames=("cfg", "refresh_every", "use_kernel", "sim",
-                          "noise_mode", "cell_bits"),
-         donate_argnums=_DONATE)
 def qail_epoch_scan(state: AmState, cfg: MemhdConfig,
                     hb: Array, qb: Array, yb: Array, mask: Array,
-                    *, refresh_every: int = 1,
-                    use_kernel: bool = False,
-                    sim=None, noise_key: Array = None,
-                    noise_mode: str = "fixed",
-                    cell_bits: Optional[int] = None,
-                    ) -> Tuple[AmState, Array]:
+                    **opts) -> Tuple[AmState, Array]:
     """One QAIL epoch as a single compiled ``lax.scan`` over minibatches.
+
+    The jitted ``_epoch_scan`` (arguments below), with the AM state
+    donated where the backend honours it.
 
     The whole epoch — sims MVM, Eq.-(4)/(5) target selection, Eq.-(6)
     scatter, and every mid-epoch binary refresh — runs device-resident in
@@ -322,6 +321,25 @@ def qail_epoch_scan(state: AmState, cfg: MemhdConfig,
       (state, n_miss) — n_miss is a DEVICE scalar; pulling it is the
       caller's one permitted host sync per epoch.
     """
+    return _epoch_scan_jit(_donate())(state, cfg, hb, qb, yb, mask, **opts)
+
+
+@cache
+def _epoch_scan_jit(donate: Tuple[int, ...]):
+    return jax.jit(_epoch_scan,
+                   static_argnames=("cfg", "refresh_every", "use_kernel",
+                                    "sim", "noise_mode", "cell_bits"),
+                   donate_argnums=donate)
+
+
+def _epoch_scan(state: AmState, cfg: MemhdConfig,
+                hb: Array, qb: Array, yb: Array, mask: Array,
+                *, refresh_every: int = 1,
+                use_kernel: bool = False,
+                sim=None, noise_key: Array = None,
+                noise_mode: str = "fixed",
+                cell_bits: Optional[int] = None,
+                ) -> Tuple[AmState, Array]:
     global _scan_trace_count
     _scan_trace_count += 1
 
@@ -428,7 +446,7 @@ def qail_epoch_batched(state: AmState, cfg: MemhdConfig,
     """
     n = h.shape[0]
     hb, qb, yb, mask = prebatch(h, queries, labels, cfg.batch_size)
-    if _DONATE:
+    if _donate():
         state = jax.tree.map(jnp.copy, state)
     state, n_miss = qail_epoch_scan(state, cfg, hb, qb, yb, mask,
                                     refresh_every=refresh_every,
@@ -462,7 +480,7 @@ def fold_feedback(state: AmState, cfg: MemhdConfig,
         raise ValueError(f"epochs must be >= 1, got {epochs}")
     n = h.shape[0]
     hb, qb, yb, mask = prebatch(h, queries, labels, cfg.batch_size)
-    if _DONATE:
+    if _donate():
         state = jax.tree.map(jnp.copy, state)
     n_miss = jnp.zeros(())
     for _ in range(epochs):

@@ -28,7 +28,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
 from repro.deploy.padding import pad_rows, round_up
 
 Array = jax.Array
@@ -112,12 +111,12 @@ class ShardedArtifact:
         fn = self._fns.get(key)
         if fn is None:
             axis = self.mesh.axis_names[0]
-            # check_rep=False: the per-shard body calls Pallas kernels,
+            # check_vma=False: the per-shard body calls Pallas kernels,
             # which have no shard_map replication rule.
-            fn = jax.jit(_shard_map(
+            fn = jax.jit(jax.shard_map(
                 local, mesh=self.mesh,
                 in_specs=(P(), P(axis)), out_specs=P(axis),
-                check_rep=False))
+                check_vma=False))
             self._fns[key] = fn
         return fn
 

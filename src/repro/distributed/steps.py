@@ -28,7 +28,7 @@ from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.models.sharding import ShardingRules, use_rules
 from repro.optim import AdamWConfig, adamw_init, adamw_update
-from repro.optim.compression import _BLOCK, axis_size, \
+from repro.optim.compression import _BLOCK, \
     ef_int8_compress, ring_all_gather, ring_reduce_scatter_int8
 
 Array = jax.Array
@@ -131,7 +131,7 @@ def _compress_pod_grads(grads: PyTree, opt_state: PyTree,
     flat_g, treedef = jax.tree.flatten(grads)
     flat_e = treedef.flatten_up_to(err_tree)
     out_g, out_e = [], []
-    n = axis_size("pod")
+    n = jax.lax.axis_size("pod")
     for g, e in zip(flat_g, flat_e):
         q, scale, new_err = ef_int8_compress(g, e)
         deq = q.astype(jnp.float32) * scale

@@ -34,6 +34,18 @@ Array = jax.Array
 TILE = 128
 
 
+def first_argmax(x: Array) -> Array:
+    """Row-wise argmax of a (bB, N) block, ties to the LOWEST index.
+
+    ``jnp.argmax``'s rule spelled out as a max and a min: the Mosaic
+    lowering of ``argmax`` does not promise which of equal maxima it
+    returns, and integer-valued similarities tie often.
+    """
+    m = jnp.max(x, axis=1, keepdims=True)
+    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.min(jnp.where(x == m, col, x.shape[1]), axis=1)
+
+
 def _make_kernel(n_valid_cols: int):
     """Bind the static valid-column count into the kernel body."""
 
@@ -60,8 +72,7 @@ def _make_kernel(n_valid_cols: int):
             neg = jnp.finfo(jnp.float32).min
             sims = jnp.where(col < n_valid_cols, sims, neg)
             blk_best = jnp.max(sims, axis=1)  # (bB,)
-            blk_arg = (c * TILE
-                       + jnp.argmax(sims, axis=1).astype(jnp.int32))
+            blk_arg = c * TILE + first_argmax(sims)
 
             @pl.when(c == 0)
             def _first():

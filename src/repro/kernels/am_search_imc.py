@@ -51,6 +51,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.deploy.padding import pad_tiles
+from repro.kernels.am_search import first_argmax
 
 Array = jax.Array
 
@@ -74,10 +75,11 @@ def _make_kernel(n_valid_cols: int, adc_bits: int, adc_clip: float,
             q_ref[...].astype(jnp.float32),
             am_ref[...].astype(jnp.float32),
             preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
         # ...its readout offset, and its ADC. Digital accumulation only
         # ever sees the quantized tile outputs.
-        part = part + off_ref[0, 0]
+        part = part + off_ref[d * nc + c]
         part = jnp.clip(part, -adc_clip, adc_clip)
         part = jnp.round(part / step) * step
         acc_ref[...] += part
@@ -90,8 +92,7 @@ def _make_kernel(n_valid_cols: int, adc_bits: int, adc_clip: float,
             neg = jnp.finfo(jnp.float32).min
             sims = jnp.where(col < n_valid_cols, sims, neg)
             blk_best = jnp.max(sims, axis=1)  # (bB,)
-            blk_arg = (c * tile_cols
-                       + jnp.argmax(sims, axis=1).astype(jnp.int32))
+            blk_arg = c * tile_cols + first_argmax(sims)
 
             @pl.when(c == 0)
             def _first():
@@ -163,7 +164,8 @@ def am_search_imc(q: Array, am_t: Array, offsets: Array | None = None, *,
         in_specs=[
             pl.BlockSpec((bb, tile_rows), lambda i, cc, d: (i, d)),
             pl.BlockSpec((tile_rows, tile_cols), lambda i, cc, d: (d, cc)),
-            pl.BlockSpec((1, 1), lambda i, cc, d: (d, cc)),
+            # Per-tile offsets, flat (d, c) row-major, read as scalars.
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((bb, 1), lambda i, cc, d: (i, 0)),
@@ -179,7 +181,7 @@ def am_search_imc(q: Array, am_t: Array, offsets: Array | None = None, *,
             pltpu.VMEM((bb,), jnp.int32),
         ],
         interpret=interpret,
-    )(qp, ap, offsets.astype(jnp.float32))
+    )(qp, ap, offsets.astype(jnp.float32).reshape(-1))
     return idx[:b, 0], sim[:b, 0]
 
 

@@ -54,6 +54,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.deploy.padding import pad_tiles
+from repro.kernels.am_search import first_argmax
 from repro.kernels.ref import multibit_adc_clip
 
 Array = jax.Array
@@ -95,7 +96,7 @@ def _make_kernel(n_valid_cols: int, cell_bits: int, adc_bits: int,
         part -= qmax * jnp.sum(q, axis=1, keepdims=True)
         # Readout drift + ADC, then digital accumulation — identical
         # epilogue to am_search_imc, in the code domain.
-        part = part + off_ref[0, 0]
+        part = part + off_ref[d * nc + c]
         part = jnp.clip(part, -adc_clip, adc_clip)
         part = jnp.round(part / step) * step
         acc_ref[...] += part
@@ -108,8 +109,7 @@ def _make_kernel(n_valid_cols: int, cell_bits: int, adc_bits: int,
             neg = jnp.finfo(jnp.float32).min
             sims = jnp.where(col < n_valid_cols, sims, neg)
             blk_best = jnp.max(sims, axis=1)  # (bB,)
-            blk_arg = (c * tile_cols
-                       + jnp.argmax(sims, axis=1).astype(jnp.int32))
+            blk_arg = c * tile_cols + first_argmax(sims)
 
             @pl.when(c == 0)
             def _first():
@@ -205,7 +205,8 @@ def am_search_multibit(q: Array, am_planes_t: Array,
             pl.BlockSpec((bb, tile_rows), lambda i, cc, d: (i, d)),
             pl.BlockSpec((n_planes, tr_p, tile_cols),
                          lambda i, cc, d: (0, d, cc)),
-            pl.BlockSpec((1, 1), lambda i, cc, d: (d, cc)),
+            # Per-tile offsets, flat (d, c) row-major, read as scalars.
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((bb, 1), lambda i, cc, d: (i, 0)),
@@ -221,7 +222,7 @@ def am_search_multibit(q: Array, am_planes_t: Array,
             pltpu.VMEM((bb,), jnp.int32),
         ],
         interpret=interpret,
-    )(qp, ap, offsets.astype(jnp.float32))
+    )(qp, ap, offsets.astype(jnp.float32).reshape(-1))
     return idx[:b, 0], sim[:b, 0]
 
 

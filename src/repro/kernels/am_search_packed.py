@@ -15,14 +15,17 @@ folds the same running-winner epilogue as ``am_search.py`` — the emitted
 (idx, sim) pair is bit-exact with the unpacked kernel (similarities are
 integer-valued, exact in float32).
 
-Geometry contract (same as ``am_search.py``): the grid is
+Geometry contract: the grid is
 
-    (B/bB, C/128, Dp/16)      # 16 packed bytes == one 128-dim slab
+    (B/bB, C/128, Dp/P)       # P = the whole packed axis (padded to 16
+                              # bytes) when Dp <= 128, else 128 bytes
 
-so one (C, D) grid step still equals one IMC array cycle and the paper's
-flagship 128x128 AM is searched in a single step — the packed kernel
-inherits the "one-shot associative search" claim (asserted against
-``repro.core.imc.cycles`` in tests/test_packed.py).
+A packed query block must be lane-aligned (a multiple of 128 bytes or
+the whole packed axis), so one grid step spans up to 1024 dims rather
+than exactly one 128x128 array. The IMC cycle count is kept as a
+function of shapes (``imc_cycles_for``: one cycle per 128x128 array,
+asserted against ``repro.core.imc.cycles`` in tests/test_packed.py), and
+the paper's flagship 128x128 AM is still searched in a single step.
 
 Padding semantics, all bit-exact with the unpacked path:
 * D tail bits / padded D slabs are packed as 0 in both query and AM, so
@@ -32,9 +35,10 @@ Padding semantics, all bit-exact with the unpacked path:
 * Ties resolve first-wins via the strict ``>`` running compare.
 
 ``mode="popcount"`` is the bit-domain path described above (pure VPU).
-``mode="unpack"`` is the fallback: each packed AM slab is unpacked to
-±1 float in VMEM and fed to the MXU exactly like ``am_search.py`` — same
-outputs, useful where int ops are slow or for cross-checking.
+``mode="unpack"`` is the fallback: the packed query and AM blocks are
+unpacked one bit plane at a time to ±1 in VMEM (bit b of every byte) and
+fed to the MXU, eight (bB, P) x (P, 128) products summing to the same
+dot — same outputs, useful where int ops are slow or for cross-checking.
 """
 from __future__ import annotations
 
@@ -45,21 +49,35 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.deploy.padding import pad_tiles
+from repro.deploy.padding import pad_tiles, round_up
+from repro.kernels.am_search import first_argmax
 
 from repro.kernels.pack_bits import pack_bits
 
 Array = jax.Array
 
-TILE = 128          # unpacked dims / centroid columns per grid step
+TILE = 128          # centroid columns per grid step (one IMC array)
 TILE_P = TILE // 8  # packed bytes per 128-dim slab
+MAX_DP_BLOCK = 128  # packed bytes per D grid step: one lane row
+ROWS = 8            # query rows per popcount step (one sublane tile)
 
 # Batch-tile height: the one free tiling knob (TILE is the IMC-array
 # contract). DEFAULT_BLOCK_B is the untuned fallback; TUNE_BLOCK_B is
-# the candidate ladder ``kernels.autotune`` searches, bounded above by
-# the VMEM footprint of the (bb, TILE_P, TILE) popcount XOR broadcast.
+# the candidate ladder ``kernels.autotune`` searches.
 DEFAULT_BLOCK_B = 256
 TUNE_BLOCK_B = (64, 128, 256, 512, 1024)
+
+
+def dp_block(dp: int) -> int:
+    """Packed bytes per D grid step: the whole axis (padded to 16 bytes)
+    when it fits one lane row, else 128-byte blocks (zero pad bytes
+    XOR-cancel, like the tail bits)."""
+    return min(round_up(dp, TILE_P), MAX_DP_BLOCK)
+
+
+def batch_block(block_b: int, b: int) -> int:
+    """Batch tile: ``block_b`` or the whole batch, in ROWS multiples."""
+    return round_up(min(block_b, max(b, 1)), ROWS)
 
 
 def _popcount8(v: Array) -> Array:
@@ -69,18 +87,25 @@ def _popcount8(v: Array) -> Array:
     return (v + (v >> 4)) & 0x0F
 
 
-def _unpack_slab(packed: Array, n_valid_rows: int, row0: Array) -> Array:
-    """(TILE_P, TILE) packed bytes -> (TILE, TILE) float in {-1, 0, +1}.
+def accumulate_hamming(q_ref, acc_ref, am_rows) -> None:
+    """acc_ref += popcount(q XOR am) for one block of packed queries.
 
-    Rows at global dim index >= n_valid_rows unpack to 0 (not -1) so the
-    MXU dot reproduces the zero-padded float kernel exactly.
+    Walks the block ROWS query rows at a time, so the (rows, P, TILE)
+    XOR broadcast stays a few vregs — in VMEM and in compile time —
+    whatever the batch tile. ``am_rows(rows)`` gives the AM bytes those
+    rows meet as int32: (P, TILE) shared by every row, or (ROWS, P,
+    TILE) gathered per query.
     """
-    p = packed.astype(jnp.int32)  # (TILE_P, TILE)
-    shifts = jnp.arange(8, dtype=jnp.int32)
-    bits = (p[:, None, :] >> shifts[:, None]) & 1  # (TILE_P, 8, TILE)
-    vals = bits.reshape(TILE, TILE).astype(jnp.float32) * 2.0 - 1.0
-    row = row0 + jax.lax.broadcasted_iota(jnp.int32, (TILE, TILE), 0)
-    return jnp.where(row < n_valid_rows, vals, 0.0)
+    def body(r, carry):
+        rows = pl.ds(pl.multiple_of(r * ROWS, ROWS), ROWS)
+        q = q_ref[rows, :].astype(jnp.int32)  # (ROWS, P)
+        a = am_rows(rows)
+        x = jax.lax.bitwise_xor(q[:, :, None], a)
+        acc_ref[rows, :] += jnp.sum(_popcount8(x), axis=1).astype(
+            jnp.float32)
+        return carry
+
+    jax.lax.fori_loop(0, q_ref.shape[0] // ROWS, body, 0)
 
 
 def _make_kernel(n_valid_cols: int, n_valid_dims: int, mode: str):
@@ -95,25 +120,25 @@ def _make_kernel(n_valid_cols: int, n_valid_dims: int, mode: str):
         def _init_acc():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
+        a = am_ref[...].astype(jnp.int32)  # (P, TILE)
         if mode == "popcount":
             # Hamming accumulation in the bit domain (VPU only).
-            q = q_ref[...].astype(jnp.int32)   # (bB, TILE_P)
-            a = am_ref[...].astype(jnp.int32)  # (TILE_P, TILE)
-            x = jax.lax.bitwise_xor(q[:, :, None], a[None, :, :])
-            acc_ref[...] += jnp.sum(_popcount8(x), axis=1).astype(
-                jnp.float32)
+            accumulate_hamming(q_ref, acc_ref, lambda rows: a[None])
         else:
-            # Unpack-in-VMEM fallback: ±1 slab through the MXU.
-            am = _unpack_slab(am_ref[...], n_valid_dims, d * TILE)
-            qb = q_ref[...].astype(jnp.int32)  # (bB, TILE_P)
-            shifts = jnp.arange(8, dtype=jnp.int32)
-            qbits = (qb[:, :, None] >> shifts) & 1  # (bB, TILE_P, 8)
-            qv = qbits.reshape(qb.shape[0], TILE).astype(jnp.float32)
-            col = d * TILE + jax.lax.broadcasted_iota(
-                jnp.int32, qv.shape, 1)
-            qv = jnp.where(col < n_valid_dims, qv * 2.0 - 1.0, 0.0)
-            acc_ref[...] += jnp.dot(
-                qv, am, preferred_element_type=jnp.float32)
+            # Unpack-in-VMEM fallback: one ±1 bit plane at a time
+            # through the MXU. Dims >= n_valid_dims (tail bits and pad
+            # bytes) are zeroed on the query side, as in the float
+            # kernel's zero padding.
+            q = q_ref[...].astype(jnp.int32)  # (bB, P)
+            byte = d * q.shape[1] + jax.lax.broadcasted_iota(
+                jnp.int32, q.shape, 1)
+            for bit in range(8):
+                qv = jnp.where(byte * 8 + bit < n_valid_dims,
+                               ((q >> bit) & 1) * 2 - 1, 0)
+                av = ((a >> bit) & 1) * 2 - 1
+                acc_ref[...] += jnp.dot(
+                    qv.astype(jnp.bfloat16), av.astype(jnp.bfloat16),
+                    preferred_element_type=jnp.float32)
 
         @pl.when(d == nd - 1)
         def _fold_winner():
@@ -127,8 +152,7 @@ def _make_kernel(n_valid_cols: int, n_valid_dims: int, mode: str):
             neg = jnp.finfo(jnp.float32).min
             sims = jnp.where(col < n_valid_cols, sims, neg)
             blk_best = jnp.max(sims, axis=1)  # (bB,)
-            blk_arg = (c * TILE
-                       + jnp.argmax(sims, axis=1).astype(jnp.int32))
+            blk_arg = c * TILE + first_argmax(sims)
 
             @pl.when(c == 0)
             def _first():
@@ -201,20 +225,21 @@ def am_search_packed(q_packed: Array, am_packed_t: Array, *,
     if not dp * 8 >= n_dims > (dp - 1) * 8:
         raise ValueError(f"n_dims={n_dims} inconsistent with Dp={dp}")
 
-    bb = min(block_b, max(b, 1))
+    p = dp_block(dp)
+    bb = batch_block(block_b, b)
     # Zero pad bytes: padded dims XOR to 0 in both operands.
-    qp = pad_tiles(q_packed, bb, TILE_P)
-    ap = pad_tiles(am_packed_t, TILE_P, TILE)
+    qp = pad_tiles(q_packed, bb, p)
+    ap = pad_tiles(am_packed_t, p, TILE)
     gb = qp.shape[0] // bb
     gc = ap.shape[1] // TILE
-    gd = qp.shape[1] // TILE_P
+    gd = qp.shape[1] // p
 
     idx, sim = pl.pallas_call(
         _make_kernel(n_cols, n_dims, mode),
         grid=(gb, gc, gd),
         in_specs=[
-            pl.BlockSpec((bb, TILE_P), lambda i, cc, d: (i, d)),
-            pl.BlockSpec((TILE_P, TILE), lambda i, cc, d: (d, cc)),
+            pl.BlockSpec((bb, p), lambda i, cc, d: (i, d)),
+            pl.BlockSpec((p, TILE), lambda i, cc, d: (d, cc)),
         ],
         out_specs=[
             pl.BlockSpec((bb, 1), lambda i, cc, d: (i, 0)),
@@ -235,9 +260,11 @@ def am_search_packed(q_packed: Array, am_packed_t: Array, *,
 
 
 def imc_cycles_for(am_packed_t_shape: tuple) -> int:
-    """(C/128)*(Dp/16) grid steps per batch tile. One 16-byte packed slab
-    covers 128 unpacked dims, so this equals the unpacked kernel's
-    (C/128)*(D/128) and must equal ``repro.core.imc.map_memhd(...).cycles``
-    — the packed deployment keeps the paper's cycle accounting."""
+    """(C/128)*(Dp/16) 128x128-array passes per batch tile. One 16-byte
+    packed slab covers 128 unpacked dims, so this equals the unpacked
+    kernel's (C/128)*(D/128) and must equal
+    ``repro.core.imc.map_memhd(...).cycles`` — the packed deployment
+    keeps the paper's cycle accounting. A function of shapes: a grid
+    step may span up to 8 arrays along D."""
     dp, c = am_packed_t_shape
     return (-(-dp // TILE_P)) * (-(-c // TILE))

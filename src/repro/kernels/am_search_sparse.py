@@ -41,7 +41,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.deploy.padding import pad_tiles
 
-from repro.kernels.am_search_packed import TILE, TILE_P, _popcount8
+from repro.kernels.am_search_packed import (
+    TILE, accumulate_hamming, batch_block, dp_block)
 from repro.kernels.am_shortlist import topk_select
 
 Array = jax.Array
@@ -97,10 +98,10 @@ def _make_kernel(n_valid_dims: int, k: int):
         def _init_acc():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        q = q_ref[...].astype(jnp.int32)       # (bB, TILE_P)
-        a = tiles_ref[...].astype(jnp.int32)   # (bB, TILE_P, TILE)
-        x = jax.lax.bitwise_xor(q[:, :, None], a)
-        acc_ref[...] += jnp.sum(_popcount8(x), axis=1).astype(jnp.float32)
+        # (ROWS, P, TILE): each query row meets its own gathered tiles.
+        accumulate_hamming(
+            q_ref, acc_ref,
+            lambda rows: tiles_ref[rows, :, :].astype(jnp.int32))
 
         @pl.when(d == nd - 1)
         def _fold_topk():
@@ -173,8 +174,9 @@ def am_search_sparse_gathered(q_packed: Array, tiles_packed: Array,
     if k < 1:
         raise ValueError(f"k={k} must be >= 1")
 
-    bb = min(block_b, max(b, 1))
-    qp = pad_tiles(q_packed, bb, TILE_P)
+    p = dp_block(dp)
+    bb = batch_block(block_b, b)
+    qp = pad_tiles(q_packed, bb, p)
     bpad, dpad = qp.shape[0] - b, qp.shape[1] - dp
     # Zero pad bytes XOR-cancel; padded rows are sliced off; padded ids
     # are -1 so no padding column can ever enter a top-k.
@@ -182,14 +184,14 @@ def am_search_sparse_gathered(q_packed: Array, tiles_packed: Array,
     ip = jnp.pad(tile_ids, ((0, bpad), (0, 0)), constant_values=-1)
     gb = qp.shape[0] // bb
     gt = tc // TILE
-    gd = qp.shape[1] // TILE_P
+    gd = qp.shape[1] // p
 
     idx, sim = pl.pallas_call(
         _make_kernel(n_dims, k),
         grid=(gb, gt, gd),
         in_specs=[
-            pl.BlockSpec((bb, TILE_P), lambda i, t, d: (i, d)),
-            pl.BlockSpec((bb, TILE_P, TILE), lambda i, t, d: (i, d, t)),
+            pl.BlockSpec((bb, p), lambda i, t, d: (i, d)),
+            pl.BlockSpec((bb, p, TILE), lambda i, t, d: (i, d, t)),
             pl.BlockSpec((bb, TILE), lambda i, t, d: (i, t)),
         ],
         out_specs=[
@@ -205,6 +207,10 @@ def am_search_sparse_gathered(q_packed: Array, tiles_packed: Array,
             pltpu.VMEM((bb, k), jnp.float32),
             pltpu.VMEM((bb, k), jnp.int32),
         ],
+        # The gathered tiles are per query, (bB, P, 128) bytes a block
+        # (double-buffered), unlike the flat kernel's shared AM block:
+        # past bB = 256 at P = 128 they outgrow the 16 MiB default.
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 << 20),
         interpret=interpret,
     )(qp, tp, ip)
     return idx[:b], sim[:b]

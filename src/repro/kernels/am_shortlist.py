@@ -11,8 +11,8 @@ wrong algorithm. The hierarchical subsystem splits the query into
      those S clusters, with a streaming top-k epilogue.
 
 The Hamming accumulation is byte-for-byte the ``am_search_packed``
-popcount path (XOR + 3-step SWAR on the VPU, same (bB, 128-col, 16-byte
-slab) grid); the epilogue differs: instead of one running argmax the
+popcount path (XOR + 3-step SWAR on the VPU, same (bB, 128-col, packed-D
+block) grid); the epilogue differs: instead of one running argmax the
 kernel keeps a per-query streaming top-S scratch, merged block-by-block
 with an iterated select-max-then-min-id reduction so results are ordered
 by (-similarity, cluster id) — ties resolve toward the LOWER cluster id,
@@ -34,7 +34,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.deploy.padding import pad_tiles
 
-from repro.kernels.am_search_packed import TILE, TILE_P, _popcount8
+from repro.kernels.am_search_packed import (
+    TILE, accumulate_hamming, batch_block, dp_block)
 
 Array = jax.Array
 
@@ -83,10 +84,8 @@ def _make_kernel(n_valid_cols: int, n_valid_dims: int, s: int):
         def _init_acc():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        q = q_ref[...].astype(jnp.int32)   # (bB, TILE_P)
-        a = am_ref[...].astype(jnp.int32)  # (TILE_P, TILE)
-        x = jax.lax.bitwise_xor(q[:, :, None], a[None, :, :])
-        acc_ref[...] += jnp.sum(_popcount8(x), axis=1).astype(jnp.float32)
+        a = am_ref[...].astype(jnp.int32)  # (P, TILE)
+        accumulate_hamming(q_ref, acc_ref, lambda rows: a[None])
 
         @pl.when(d == nd - 1)
         def _fold_topk():
@@ -157,19 +156,20 @@ def am_shortlist(q_packed: Array, super_packed_t: Array, *,
     if not dp * 8 >= n_dims > (dp - 1) * 8:
         raise ValueError(f"n_dims={n_dims} inconsistent with Dp={dp}")
 
-    bb = min(block_b, max(b, 1))
-    qp = pad_tiles(q_packed, bb, TILE_P)
-    ap = pad_tiles(super_packed_t, TILE_P, TILE)
+    p = dp_block(dp)
+    bb = batch_block(block_b, b)
+    qp = pad_tiles(q_packed, bb, p)
+    ap = pad_tiles(super_packed_t, p, TILE)
     gb = qp.shape[0] // bb
     gc = ap.shape[1] // TILE
-    gd = qp.shape[1] // TILE_P
+    gd = qp.shape[1] // p
 
     idx, sim = pl.pallas_call(
         _make_kernel(n_cols, n_dims, s),
         grid=(gb, gc, gd),
         in_specs=[
-            pl.BlockSpec((bb, TILE_P), lambda i, cc, d: (i, d)),
-            pl.BlockSpec((TILE_P, TILE), lambda i, cc, d: (d, cc)),
+            pl.BlockSpec((bb, p), lambda i, cc, d: (i, d)),
+            pl.BlockSpec((p, TILE), lambda i, cc, d: (d, cc)),
         ],
         out_specs=[
             pl.BlockSpec((bb, s), lambda i, cc, d: (i, 0)),

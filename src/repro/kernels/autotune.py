@@ -69,7 +69,6 @@ DEFAULT_CACHE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                              "autotune_cache.json")
 DEFAULT_VMEM_BUDGET_MB = 8.0
 TILE = 128
-TILE_P = TILE // 8
 
 
 @dataclasses.dataclass(frozen=True)
@@ -95,10 +94,19 @@ def _asp_inputs(rng, batch, dims):
     return ref.pack_rows(q), ref.pack_rows(am).T, d
 
 
+def _packed_block_vmem(bb, d, per_query_am: bool = False):
+    """Double-buffered (bb, P) query and (P, TILE) AM byte blocks — the
+    AM block is per query, (bb, P, TILE), for the sparse kernel — plus
+    the (ROWS, P, TILE) int32 XOR broadcast and the f32 accumulator."""
+    p = _asp.dp_block(-(-d // 8))
+    am = bb * p * TILE if per_query_am else p * TILE
+    return (2 * (bb * p + am) + _asp.ROWS * p * TILE * 4
+            + bb * TILE * 4)
+
+
 def _asp_vmem(bb, dims):
-    # Dominant term: the (bb, TILE_P, TILE) int32 XOR broadcast of the
-    # popcount path; plus the f32 accumulator and winner scratch.
-    return bb * TILE_P * TILE * 4 + bb * TILE * 4 + bb * 8
+    # Packed blocks + accumulator, plus the winner scratch.
+    return _packed_block_vmem(bb, dims["D"]) + bb * 8
 
 
 def _ef_inputs(rng, batch, dims):
@@ -110,8 +118,9 @@ def _ef_inputs(rng, batch, dims):
 
 
 def _ef_vmem(bb, dims):
-    # x block + w block + f32 accumulator + packed out block.
-    return bb * TILE * 4 * 2 + TILE * TILE * 4 + bb * TILE_P
+    # x and w blocks (double-buffered) + f32 accumulator + packed out.
+    td = min(-(-dims["D"] // TILE) * TILE, _ef.MAX_TD)
+    return 2 * (bb * TILE * 4 + TILE * td * 4) + bb * td * 4 + bb * td // 8
 
 
 def _qu_inputs(rng, batch, dims):
@@ -155,7 +164,7 @@ def _amb_inputs(rng, batch, dims):
 def _amb_vmem(bb, dims):
     # q block + the per-plane unpacked {0,1} slab + int32 bit broadcast
     # + partial/accumulator blocks and winner scratch.
-    return (bb * TILE * 4 + TILE * TILE * 4 + TILE_P * 8 * TILE * 4
+    return (bb * TILE * 4 + TILE * TILE * 4 + TILE * TILE * 4
             + 2 * bb * TILE * 4 + bb * 8)
 
 
@@ -169,10 +178,9 @@ def _shl_inputs(rng, batch, dims):
 
 
 def _shl_vmem(bb, dims):
-    # XOR broadcast + accumulator + the (bb, S + TILE) top-S merge pair.
+    # Packed blocks + accumulator + the (bb, S + TILE) top-S merge pair.
     s = dims["S"]
-    return (bb * TILE_P * TILE * 4 + bb * TILE * 4
-            + 2 * bb * (s + TILE) * 8)
+    return _packed_block_vmem(bb, dims["D"]) + 2 * bb * (s + TILE) * 8
 
 
 def _ass_inputs(rng, batch, dims):
@@ -195,10 +203,10 @@ def _ass_inputs(rng, batch, dims):
 
 
 def _ass_vmem(bb, dims):
-    # Per-query uint8 tile block + its int32 XOR broadcast + accumulator
-    # + the (bb, K + TILE) top-k merge pair.
+    # Per-query packed tile blocks + accumulator + the (bb, K + TILE)
+    # top-k merge pair.
     k = dims["K"]
-    return (bb * TILE_P * TILE * 5 + bb * TILE * 4
+    return (_packed_block_vmem(bb, dims["D"], per_query_am=True)
             + 2 * bb * (k + TILE) * 8)
 
 

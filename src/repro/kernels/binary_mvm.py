@@ -40,6 +40,7 @@ def _mvm_kernel(x_ref, w_ref, o_ref):
         x_ref[...].astype(jnp.float32),
         w_ref[...].astype(jnp.float32),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 

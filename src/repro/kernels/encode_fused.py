@@ -5,14 +5,20 @@ round-trip the (B, D) float hypervector through HBM, binarize it, pack
 it, and only then dispatch the XOR+popcount search. But the only thing
 the search ever reads is one *bit* per dimension (sign(H) >= 0), so the
 float H is pure HBM traffic. This kernel closes that gap: it tiles the
-bipolar projection MVM over 128x128 blocks exactly like
-``binary_mvm.py`` (grid == the IMC cycle count of the encoder mapping),
-keeps the accumulator in VMEM across K slabs, and on the last K step
-emits the sign-binarized, uint8-packed query row directly — no float H
-ever touches HBM.
+bipolar projection MVM over 128-row K slabs, keeps the accumulator in
+VMEM across them, and on the last K step emits the sign-binarized,
+uint8-packed query row directly — no float H ever touches HBM.
 
-    grid = (B/bB, D/128, f/128)      # f innermost: accumulation
-    out block per (i, j): (bB, 16) uint8 — one packed 128-dim slab
+    grid = (B/bB, D/TD, f/128)       # f innermost: accumulation
+    TD = D padded to 128, at most 1024 dims per block
+    out block per (i, j): (bB, TD/8) uint8 — the whole packed row, or
+    one lane-aligned 128-byte slab of it
+
+The packed output block must be lane-aligned (a multiple of 128 bytes
+or the whole packed axis), so one D block spans up to 1024 dims rather
+than one 128x128 array; the IMC cycle count stays a function of shapes
+(``imc_cycles_for``). The bitpack epilogue is ``pack_bits.pack_lanes``
+(an exact 0/1 MXU matmul; Mosaic cannot reshape lanes into bytes).
 
 Bit semantics are exactly the staged chain's
 ``encode_query -> pack_rows``: a bit is 1 iff the accumulated H >= 0
@@ -26,9 +32,11 @@ Parity caveat: for f > 128 the kernel sums the MVM in 128-wide K slabs
 while the staged einsum may reduce in a different order, so for
 *non-integer* features the two H values can differ by float rounding —
 a bit flips only when the true H sits within that rounding error of 0.
-Bipolar/integer features are exact (integer accumulation); float
-features agree for every tested geometry and seed, but "bit-exact" is
-a structural guarantee only where H is integer-valued.
+Both sides contract at float32 precision (``Precision.HIGHEST``; the
+TPU default would round the features to bf16). Bipolar/integer
+features are exact (integer accumulation); float features agree for
+every tested geometry and seed, but "bit-exact" is a structural
+guarantee only where H is integer-valued.
 
 ``search_from_features`` / ``predict_from_features`` chain this kernel
 straight into ``am_search_packed`` under ONE jit — the whole
@@ -44,14 +52,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.deploy.padding import pad_tiles
+from repro.deploy.padding import pad_tiles, round_up
 
 from repro.kernels.am_search_packed import am_search_packed
+from repro.kernels.pack_bits import pack_lanes
 
 Array = jax.Array
 
 TILE = 128          # IMC array dim == MXU tile dim
-TILE_P = TILE // 8  # packed bytes per 128-dim slab
+MAX_TD = 1024       # dims per D block: 128 packed bytes, one lane row
 
 # Batch-tile height: the free tiling knob (TILE is the IMC-geometry /
 # MXU contract). ``kernels.autotune`` searches TUNE_BLOCK_B and ops.py
@@ -75,20 +84,18 @@ def _make_kernel(n_valid_dims: int):
             x_ref[...].astype(jnp.float32),
             w_ref[...].astype(jnp.float32),
             preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
 
         @pl.when(k == nk - 1)
         def _sign_and_pack():
-            h = acc_ref[...]  # (bB, TILE)
-            col = j * TILE + jax.lax.broadcasted_iota(
+            h = acc_ref[...]  # (bB, TD)
+            col = j * h.shape[1] + jax.lax.broadcasted_iota(
                 jnp.int32, h.shape, 1)
             # bit 1 iff H >= 0 (binarize_query: sign(0) -> +1, and
             # pack_bits packs +1 as 1); padded D columns pack as 0.
-            bits = ((h >= 0) & (col < n_valid_dims)).astype(jnp.int32)
-            bits = bits.reshape(h.shape[0], TILE_P, 8)
-            weights = (2 ** jnp.arange(8, dtype=jnp.int32))
-            o_ref[...] = jnp.sum(bits * weights, axis=-1).astype(
-                jnp.uint8)
+            bits = (h >= 0) & (col < n_valid_dims)
+            o_ref[...] = pack_lanes(bits.astype(jnp.float32))
 
     return kernel
 
@@ -117,22 +124,23 @@ def encode_pack(feats: Array, projection: Array, *,
     assert f == f2, (feats.shape, projection.shape)
 
     bb = min(block_b, max(b, 1))
+    td = min(round_up(d, TILE), MAX_TD)
     xp = pad_tiles(feats.astype(jnp.float32), bb, TILE)
-    wp = pad_tiles(projection.astype(jnp.float32), TILE, TILE)
+    wp = pad_tiles(projection.astype(jnp.float32), TILE, td)
     gb, gf, gd = (xp.shape[0] // bb, xp.shape[1] // TILE,
-                  wp.shape[1] // TILE)
+                  wp.shape[1] // td)
 
     out = pl.pallas_call(
         _make_kernel(d),
         grid=(gb, gd, gf),
         in_specs=[
             pl.BlockSpec((bb, TILE), lambda i, j, k: (i, k)),
-            pl.BlockSpec((TILE, TILE), lambda i, j, k: (k, j)),
+            pl.BlockSpec((TILE, td), lambda i, j, k: (k, j)),
         ],
-        out_specs=pl.BlockSpec((bb, TILE_P), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((xp.shape[0], gd * TILE_P),
+        out_specs=pl.BlockSpec((bb, td // 8), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((xp.shape[0], wp.shape[1] // 8),
                                        jnp.uint8),
-        scratch_shapes=[pltpu.VMEM((bb, TILE), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bb, td), jnp.float32)],
         interpret=interpret,
     )(xp, wp)
     return out[:b, : -(-d // 8)]
@@ -186,9 +194,10 @@ def predict_from_features(feats: Array, projection: Array,
 
 
 def imc_cycles_for(feats_shape: tuple, projection_shape: tuple) -> int:
-    """Grid size of the f x D tiling — identical to ``binary_mvm``'s,
-    so the fused encoder keeps the encoder-mapping cycle count of
-    ``repro.core.imc.map_basic(f, D)`` (the pack epilogue rides the last
-    accumulation step for free)."""
+    """128x128-array passes of the f x D projection — identical to
+    ``binary_mvm``'s, so the fused encoder keeps the encoder-mapping
+    cycle count of ``repro.core.imc.map_basic(f, D)`` (the pack epilogue
+    rides the last accumulation step for free). A function of shapes:
+    the Pallas grid takes up to 8 arrays along D per step."""
     f, d = projection_shape
     return (-(-f // TILE)) * (-(-d // TILE))

@@ -5,8 +5,13 @@ projection matrix at 1 bit per cell. These kernels realize that storage
 format on TPU: bipolar (+-1) tiles are packed 8 cells/byte (LSB-first)
 for HBM residence and unpacked tile-by-tile into VMEM for compute.
 
-Both kernels are purely element-wise over (R, C) tiles, so blocks are
-(block_r, 1024) lanes — VPU work, no MXU involvement.
+Blocks are (block_r, 1024) cells <-> (block_r, 128) bytes, so both sides
+are lane-aligned. Mosaic cannot reshape a lane axis into (bytes, 8), so
+the byte <-> bit regrouping runs on the MXU instead: packing multiplies
+the {0, 1} cells by the (L, L/8) byte-weight matrix (``pack_lanes``),
+unpacking replicates each byte over its 8 lanes with a 0/1 spread matrix
+and shifts out one bit per lane. Both are exact: every operand is 0, 1
+or a power of two (bf16-exact) and every sum is at most 255.
 """
 from __future__ import annotations
 
@@ -23,20 +28,42 @@ Array = jax.Array
 LANES = 1024  # unpacked cells per block column; packed cols = LANES // 8
 
 
+def _byte_of(shape: tuple, cell_axis: int) -> tuple[Array, Array]:
+    """(cell, byte) membership and bit weight over a cells x bytes grid;
+    ``cell_axis`` says which axis of ``shape`` indexes cells."""
+    cell = jax.lax.broadcasted_iota(jnp.int32, shape, cell_axis)
+    byte = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - cell_axis)
+    return (cell >> 3) == byte, jnp.left_shift(1, cell & 7)
+
+
+def pack_lanes(bits: Array) -> Array:
+    """(R, L) {0, 1} cells -> (R, L // 8) uint8, LSB-first, on the MXU.
+
+    Kernel-side packer shared with ``encode_fused``: one bf16 matmul
+    against the byte-weight matrix, exact (sums of distinct powers of
+    two below 256).
+    """
+    n = bits.shape[1]
+    member, weight = _byte_of((n, n // 8), 0)
+    w = jnp.where(member, weight, 0).astype(jnp.bfloat16)
+    packed = jnp.dot(bits.astype(jnp.bfloat16), w,
+                     preferred_element_type=jnp.float32)
+    return packed.astype(jnp.int32).astype(jnp.uint8)
+
+
 def _pack_kernel(x_ref, o_ref):
-    x = x_ref[...]  # (bR, LANES)
-    br = x.shape[0]
-    bits = (x > 0).astype(jnp.int32).reshape(br, LANES // 8, 8)
-    weights = (2 ** jnp.arange(8, dtype=jnp.int32))
-    o_ref[...] = jnp.sum(bits * weights, axis=-1).astype(jnp.uint8)
+    o_ref[...] = pack_lanes((x_ref[...] > 0).astype(jnp.float32))
 
 
 def _unpack_kernel(p_ref, o_ref):
-    p = p_ref[...].astype(jnp.int32)  # (bR, LANES // 8)
-    br = p.shape[0]
-    shifts = jnp.arange(8, dtype=jnp.int32)
-    bits = (p[:, :, None] >> shifts) & 1  # (bR, LANES//8, 8)
-    o_ref[...] = (bits.reshape(br, LANES).astype(jnp.float32) * 2 - 1)
+    member, _ = _byte_of((LANES // 8, LANES), 1)
+    spread = member.astype(jnp.bfloat16)  # byte j -> its 8 lanes
+    p = p_ref[...].astype(jnp.int32).astype(jnp.bfloat16)
+    byte = jnp.dot(p, spread,
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, byte.shape, 1)
+    bit = (byte >> (lane & 7)) & 1
+    o_ref[...] = bit.astype(jnp.float32) * 2 - 1
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))

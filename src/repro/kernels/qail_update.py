@@ -13,9 +13,11 @@ blocks only, with the transposed binary AM, the update payload and the
 (C, D) delta accumulator resident in VMEM across steps. Scatter-free by
 construction — target selection becomes a one-hot selection matrix W
 (B, C) with W[i] = lr*mis_i*(onehot(true) - onehot(pred)), and the delta
-is the MXU matmul W^T @ upd accumulated over query blocks. The miss
-count rides along in a (1, 1) accumulator, so training needs no second
-pass to know its error rate.
+is the MXU matmul W^T @ upd accumulated over query blocks, contracted at
+float32 precision (the payload is float; the TPU default would round it
+to bf16). The miss count rides along in a lane-wide (1, 128)
+accumulator (Mosaic stores no scalars to VMEM), so training needs no
+second pass to know its error rate.
 
 Padded columns are masked to -inf before both argmaxes (they can never
 be selected); padded rows carry mask 0 and label -1 (their W row is
@@ -33,6 +35,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.deploy.padding import pad_tiles, pad_vec
+from repro.kernels.am_search import first_argmax
 
 Array = jax.Array
 
@@ -44,6 +47,16 @@ TILE = 128
 # applies the cached winner; DEFAULT_BLOCK_B is the fallback.
 DEFAULT_BLOCK_B = 256
 TUNE_BLOCK_B = (64, 128, 256, 512, 1024)
+
+
+def _vmem_limit(bb: int, d: int, c: int) -> int:
+    """Scoped VMEM for one call: the double-buffered blocks (queries,
+    payload, resident AM, (C, D) delta), the delta temporary and the
+    (bB, C) selection intermediates, in float32 words, doubled for
+    headroom. The AM and delta alone exceed the 16 MiB default at
+    1024x1024; a v5e core has 128 MiB."""
+    words = 2 * (2 * bb * d + 2 * d * c) + d * c + 8 * bb * c
+    return min(max(2 * 4 * words, 32 << 20), 100 << 20)
 
 
 def _make_kernel(n_valid_cols: int, lr: float):
@@ -70,24 +83,26 @@ def _make_kernel(n_valid_cols: int, lr: float):
         labels = y_ref[...]            # (bB, 1) int32, padded rows = -1
 
         # Eq. (4): global argmax -> push-away target, one-hot on C.
-        pred_t = jnp.argmax(sims, axis=1)  # (bB,)
+        pred_t = first_argmax(sims)  # (bB,)
         pred_hot = col == pred_t[:, None]  # (bB, C)
-        pred_class = jnp.sum(jnp.where(pred_hot, owners, 0), axis=1)
+        pred_class = jnp.sum(jnp.where(pred_hot, owners, 0), axis=1,
+                             keepdims=True)  # (bB, 1)
 
         # Eq. (5): argmax within the true class -> pull-toward target.
         own_mask = (owners == labels) & valid  # (bB, C)
-        true_t = jnp.argmax(jnp.where(own_mask, sims, neg), axis=1)
+        true_t = first_argmax(jnp.where(own_mask, sims, neg))
         true_hot = col == true_t[:, None]
 
-        mis = ((pred_class != labels[:, 0]).astype(jnp.float32)
-               * mask_ref[...][:, 0])  # (bB,)
+        mis = ((pred_class != labels).astype(jnp.float32)
+               * mask_ref[...])  # (bB, 1)
 
         # Eq. (6) as a selection matmul: delta += W^T @ upd on the MXU.
-        w = (lr * mis)[:, None] * (true_hot.astype(jnp.float32)
-                                   - pred_hot.astype(jnp.float32))
+        w = (lr * mis) * (true_hot.astype(jnp.float32)
+                          - pred_hot.astype(jnp.float32))
         delta_ref[...] += jnp.dot(w.T, upd_ref[...].astype(jnp.float32),
-                                  preferred_element_type=jnp.float32)
-        miss_ref[0, 0] += jnp.sum(mis)
+                                  preferred_element_type=jnp.float32,
+                                  precision=jax.lax.Precision.HIGHEST)
+        miss_ref[...] += jnp.sum(mis, axis=0, keepdims=True)
         del nb
 
     return kernel
@@ -149,12 +164,14 @@ def qail_update(q: Array, upd: Array, am_t: Array, centroid_class: Array,
         ],
         out_specs=[
             pl.BlockSpec((c + pc, dd + pd), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec((1, TILE), lambda i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((c + pc, dd + pd), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((1, TILE), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(bb, dd + pd, c + pc)),
         interpret=interpret,
     )(qp, up, ap, ownp, yp, mp)
     return delta[:c, :dd], miss[0, 0]

@@ -13,12 +13,13 @@ Array = jax.Array
 
 
 def binary_mvm(x: Array, w: Array) -> Array:
-    """H = x @ w with float32 accumulation.
+    """H = x @ w with float32 accumulation, at float32 precision.
 
     x: (B, K) features or queries; w: (K, N) bipolar projection/AM weights.
     """
     return jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32),
-                   preferred_element_type=jnp.float32)
+                   preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
 
 
 def am_search(q: Array, am_t: Array) -> tuple[Array, Array]:
@@ -254,7 +255,8 @@ def am_search_imc(q: Array, am_t: Array, *, tile_rows: int, tile_cols: int,
     ar = ap.reshape(gd, tile_rows, gc, tile_cols)
     # One (g, h) slot == one physical array's analog MVM output.
     part = jnp.einsum("bgr,grhc->bghc", qr, ar,
-                      preferred_element_type=jnp.float32)
+                      preferred_element_type=jnp.float32,
+                      precision=jax.lax.Precision.HIGHEST)
     if offsets is not None:
         part = part + offsets[None, :, :, None]
     part = adc_quantize(part, adc_bits, adc_clip)
@@ -391,5 +393,6 @@ def qail_update_delta(q: Array, upd: Array, am_t: Array,
         jax.nn.one_hot(true_t, c, dtype=jnp.float32)
         - jax.nn.one_hot(pred_t, c, dtype=jnp.float32))  # (B, C)
     delta = jnp.dot(w.T, upd.astype(jnp.float32),
-                    preferred_element_type=jnp.float32)  # (C, D)
+                    preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST)  # (C, D)
     return delta, mis.sum()

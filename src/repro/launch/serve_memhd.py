@@ -45,7 +45,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
-from repro import obs
+from repro import compile_cache, obs
 
 # Shared tile-padding helpers (re-exported here for existing callers).
 from repro.deploy.padding import pad_to_multiple, round_up  # noqa: F401
@@ -288,7 +288,8 @@ def build_report(deployed, requests: Sequence[Request], stats: Dict,
     ``backend`` / ``devices`` fields make reports from different
     substrates and device counts comparable. ``metrics`` is the
     runtime-introspection section (``metrics_summary()``); it defaults
-    to a fresh summary with no steady-state window.
+    to a fresh summary with no steady-state window. ``device`` names
+    what served the stream (``obs.device_info``).
     """
     n_rows = sum(r.size for r in requests)
     devices = int(getattr(deployed, "n_devices", 1))
@@ -296,6 +297,7 @@ def build_report(deployed, requests: Sequence[Request], stats: Dict,
     return {
         "workload": "memhd_classify",
         "backend": deployed.backend,
+        "device": obs.device_info(),
         "devices": devices,
         "packed": bool(getattr(deployed, "packed", False)),
         "mode": deployed.serving_mode,
@@ -365,6 +367,7 @@ def main():
                     help="structured one-JSON-per-line logging")
     args = ap.parse_args()
     obs.setup_logging(json_mode=args.log_json)
+    compile_cache.enable()
     obs.install()  # count XLA compiles from the very first trace
 
     if args.target and args.unpacked:

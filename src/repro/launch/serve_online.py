@@ -36,7 +36,7 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
-from repro import obs
+from repro import compile_cache, obs
 
 log = logging.getLogger("serve_online")
 
@@ -120,6 +120,7 @@ def main(argv: Optional[List[str]] = None):
     ap.add_argument("--log-json", action="store_true")
     args = ap.parse_args(argv)
     obs.setup_logging(json_mode=args.log_json)
+    compile_cache.enable()
     obs.install()
 
     from repro.core import EncoderConfig, MemhdConfig, MemhdModel

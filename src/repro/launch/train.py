@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import obs
+from repro import compile_cache, obs
 
 log = logging.getLogger("train")
 
@@ -349,6 +349,7 @@ def main():
     cfg = TrainRunConfig(**{f.name: getattr(args, f.name)
                             for f in dataclasses.fields(TrainRunConfig)})
     obs.setup_logging(json_mode=cfg.log_json)
+    compile_cache.enable()
     obs.install()  # jit compile counters for the run_end event
     out = run(cfg)
     print(json.dumps(out, indent=1))

@@ -29,7 +29,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map as _shard_map
 from repro.models.config import AttnSpec, FfnSpec, SsmSpec
 from repro.models.sharding import shard_act
 
@@ -392,7 +391,7 @@ def attention_decode_seqpar(q: Array, k_cache: Array, v_cache: Array,
         out = (acc_g / jnp.maximum(l_g[..., None], 1e-20)).astype(q_l.dtype)
         return jnp.einsum("bhqd->bqhd", out), kc, vc
 
-    out, new_k, new_v = _shard_map(
+    out, new_k, new_v = jax.shard_map(
         local, mesh=mesh,
         in_specs=(bspec(None, None, None), bspec("model", None, None),
                   bspec("model", None, None), bspec(None, None),
@@ -967,7 +966,7 @@ def _moe_ffn_sharded(p: Params, spec: FfnSpec, x: Array, rules,
 
     bias_in = (router_bias[None] if router_bias is not None
                else jnp.zeros((1, e), jnp.float32))
-    y_flat, counts, probs_mean = _shard_map(
+    y_flat, counts, probs_mean = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(all_axes, None), P(), P(), P(exp_axes),
                   P(exp_axes), P(exp_axes)),

@@ -10,8 +10,8 @@ Three pillars, one import:
     optional ``jax.profiler.TraceAnnotation`` bridge.
   * ``repro.obs.jaxmon`` — JAX runtime introspection: jit
     compile/recompile counters via ``jax.monitoring``, per-device
-    memory gauges, and the ``assert_no_recompiles`` steady-state
-    helper.
+    memory gauges, the ``assert_no_recompiles`` steady-state helper,
+    and ``device_info`` (what every report names as its device).
 
 Plus the shared driver plumbing: ``setup_logging`` (one consistent
 format for every launch driver, ``--log-json`` structured option) and
@@ -22,8 +22,8 @@ touches jax, and only lazily (safe to import repro.obs anywhere).
 """
 from repro.obs import jaxmon, metrics, trace
 from repro.obs.jaxmon import (
-    RecompileError, assert_no_recompiles, count_compiles, install,
-    update_memory_gauges,
+    RecompileError, assert_no_recompiles, count_compiles, device_info,
+    install, update_memory_gauges,
 )
 from repro.obs.logs import EventLog, setup_logging
 from repro.obs.metrics import (
@@ -38,6 +38,6 @@ __all__ = [
     "snapshot", "render_prometheus", "timed_ms",
     "TRACER", "span", "export_chrome_trace",
     "install", "count_compiles", "assert_no_recompiles",
-    "RecompileError", "update_memory_gauges",
+    "RecompileError", "update_memory_gauges", "device_info",
     "setup_logging", "EventLog",
 ]

@@ -122,6 +122,18 @@ def assert_no_recompiles(what: str = "steady-state region"):
             f"{before + delta})")
 
 
+def device_info() -> Dict[str, object]:
+    """The devices this process runs on, as JAX reports them: the first
+    device's platform and kind, and the device count. Every report the
+    program emits names them, so a CPU number never passes for a chip
+    number."""
+    import jax
+
+    devs = jax.devices()
+    return {"platform": devs[0].platform,
+            "device_kind": devs[0].device_kind, "count": len(devs)}
+
+
 def update_memory_gauges() -> Dict[str, Dict[str, float]]:
     """Per-device ``memory_stats()`` -> gauges; returns what it set.
 

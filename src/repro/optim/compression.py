@@ -20,8 +20,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size  # noqa: F401  (re-export for callers)
-
 Array = jax.Array
 PyTree = Any
 
@@ -77,7 +75,7 @@ def ring_reduce_scatter_int8(deq: Array, axis_name: str) -> Array:
       (nblocks/n, _BLOCK) fp32 — this member's fully-reduced chunk
       ((me + 1) mod n in chunk order).
     """
-    n = axis_size(axis_name)  # static: mesh sizes are known
+    n = jax.lax.axis_size(axis_name)  # static: mesh sizes are known
     me = jax.lax.axis_index(axis_name)
     nb = deq.shape[0]
     if nb % n:
@@ -107,7 +105,7 @@ def ring_all_gather(x: Array, axis_name: str) -> Array:
     concatenated along axis 0. Payload stays fp32 (the reduced gradient
     must be exact); the *reduce* leg is where compression pays.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     perm = [(j, (j + 1) % n) for j in range(n)]
 

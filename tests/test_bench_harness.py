@@ -389,7 +389,7 @@ class TestAutotune:
                                      iters=1, vmem_budget_mb=1e-6)
         entry = autotune.autotune_kernel(
             "am_search_packed", dims, batch=1024, iters=1,
-            vmem_budget_mb=1.0)  # 1 MB: only block_b=64 fits
+            vmem_budget_mb=0.125)  # 128 KiB: only block_b=64 fits
         assert entry["skipped_vmem"]
 
     def test_geometry_key_requires_dims(self):

@@ -16,7 +16,6 @@ def test_ring_collectives_match_allreduce():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.optim.compression import ring_reduce_scatter_int8, ring_all_gather, _BLOCK
-from repro.compat import shard_map
 
 mesh = jax.make_mesh((8,), ("pod",))
 rng = np.random.default_rng(0)
@@ -26,7 +25,7 @@ def f(gl):
     red = ring_reduce_scatter_int8(gl[0], "pod")
     return ring_all_gather(red, "pod")[None]
 
-out = shard_map(f, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"))(g)
+out = jax.shard_map(f, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"))(g)
 want = np.asarray(g.sum(axis=0))
 got = np.asarray(out)[3]
 rel = np.abs(got - want).max() / np.abs(want).max()

@@ -108,8 +108,10 @@ class TestPackedGridContract:
     """Kernel geometry == IMC cost model, packed == unpacked."""
 
     def test_one_shot_for_paper_flagship(self):
-        # The paper's 128x128 flagship: the packed search is literally
-        # ONE grid step — one IMC array cycle, as am_search.py promises.
+        # The paper's 128x128 flagship: the packed search is ONE IMC
+        # array cycle, as am_search.py promises. The count is a function
+        # of shapes; the Pallas grid may span up to 8 arrays along D per
+        # step (a lane-aligned 128-byte block), so it is not the grid.
         apt_shape = (128 // 8, 128)  # (Dp, C) of the packed AM
         assert packed_cycles(apt_shape) == 1
         assert packed_cycles(apt_shape) == \

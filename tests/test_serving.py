@@ -173,11 +173,14 @@ class TestReportSchema:
 
     ``backend`` + ``devices`` (and the per-device throughput) make
     reports from different deployment backends and device counts
-    comparable — asserted here for every registered backend.
+    comparable — asserted here for every registered backend. ``device``
+    names what served the stream (platform, kind, count), so a CPU
+    report never passes for a chip report.
     """
 
     KEYS = {
-        "workload", "backend", "devices", "packed", "mode", "pipeline",
+        "workload", "backend", "device", "devices", "packed", "mode",
+        "pipeline",
         "topk", "geometry", "requests", "rows", "wall_s", "qps",
         "rows_per_s", "rows_per_s_per_device", "resident_am_bytes",
         "am_memory_ratio", "metrics", "depth", "batches", "rows_real",
@@ -205,6 +208,10 @@ class TestReportSchema:
             assert rep["workload"] == "memhd_classify"
             assert rep["backend"] == "packed"
             assert rep["devices"] == 1
+            dev = jax.devices()[0]
+            assert rep["device"] == {"platform": dev.platform,
+                                     "device_kind": dev.device_kind,
+                                     "count": len(jax.devices())}
             assert rep["rows"] == sum(r.size for r in reqs)
             assert rep["qps"] == round(len(reqs) / 0.25, 1)
             assert rep["rows_per_s_per_device"] == rep["rows_per_s"]
