@@ -1,0 +1,95 @@
+"""The harness finds every file BENCHMARK.json names, and refuses to
+measure off the chip or without the program."""
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+ROOT = Path(__file__).resolve().parents[2]
+SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+
+
+def _run_py():
+    import importlib.util
+    spec = importlib.util.spec_from_file_location("bench_run",
+                                                  ROOT / "bench" / "run.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in SPEC["workloads"]])
+def test_every_cell_loads_by_name(cell):
+    from bench import systems
+    run = _run_py()
+    c = run.load_cell(cell)
+    assert c["cfg"]["system"] in systems.SYSTEMS
+    __import__(f"bench.modes.{c['traffic']['mode']}")
+    assert set(c["limits"]["limits"])
+    names = {m["name"] for m in c["end_to_end"]}
+    assert "setup_s" in names and len(names) >= 2
+    assert c["per_layer"], "every cell reports a per-layer metric"
+    for m in c["per_layer"]:
+        assert callable(run.reader(m["name"]))
+
+
+def test_benchmark_json_names_and_files():
+    keys = {"command", "paths", "run_seconds", "configs", "workloads",
+            "end_to_end", "per_layer"}
+    assert set(SPEC) == keys
+    assert SPEC["command"] == ["python3", "bench/run.py"]
+    for c in SPEC["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert NAME.match(c["name"])
+        cfg = json.loads((ROOT / c["file"]).read_text())
+        assert cfg["reduced"] == c["reduced"]
+        assert c["file"].startswith("bench/")
+    used = {w["config"] for w in SPEC["workloads"]}
+    assert used == {c["name"] for c in SPEC["configs"]}
+    for w in SPEC["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert NAME.match(w["name"]) and len(w["why"]) <= 200
+    metrics = SPEC["end_to_end"] + SPEC["per_layer"]
+    assert len({m["name"] for m in metrics}) == len(metrics)
+    for m in metrics:
+        assert NAME.match(m["name"]) and m["better"] in ("lower", "higher")
+        assert re.match(r"^[A-Za-z0-9_/%.-]{1,16}$", m["unit"])
+    for m in SPEC["end_to_end"]:
+        assert 0.01 <= m["bound"] <= 0.25
+    for m in SPEC["per_layer"]:
+        assert (BENCH_METRICS / f"{m['name']}.py").is_file()
+        assert m["moves"] in {e["name"] for e in SPEC["end_to_end"]}
+
+
+BENCH_METRICS = ROOT / "bench" / "metrics"
+
+
+def _bench(cwd, *args, env=None):
+    return subprocess.run(
+        [sys.executable, "bench/run.py", "--workload", "mnist1024.bulk",
+         "--seed", "1", "--seconds", "1", "--trace", "0", *args],
+        cwd=cwd, capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, **(env or {})))
+
+
+def test_no_tpu_no_result():
+    out = _bench(ROOT, env={"JAX_PLATFORMS": "cpu"})
+    assert out.returncode != 0
+    assert out.stdout.strip() == ""
+    assert "no TPU" in out.stderr
+
+
+def test_benchmark_files_alone_no_result(tmp_path):
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    env = {"JAX_PLATFORMS": "cpu", "PYTHONPATH": ""}
+    out = _bench(tmp_path, env=env)
+    assert out.returncode != 0
+    assert out.stdout.strip() == ""
