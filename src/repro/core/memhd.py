@@ -34,6 +34,7 @@ from repro.core import am as am_lib
 from repro.core import encoding, evaluate as eval_lib, init as init_lib, qail
 from repro.core.imc import ImcArrayConfig, memhd_pipeline
 from repro.core.types import EncoderConfig, MemhdConfig
+from repro.obs import span
 
 Array = jax.Array
 log = logging.getLogger(__name__)
@@ -171,6 +172,11 @@ class MemhdModel:
         ``qail_epoch_scan`` dispatch — one host sync per epoch (the
         ``float(miss)`` for the history record).
 
+        Host spans (``repro.obs.span``): ``fit`` covers the call;
+        ``fit.encode`` the encode, binarize and batching dispatch; in
+        each epoch ``fit.epoch`` the epoch's dispatch and ``fit.sync``
+        the miss count's host sync (both with ``epoch=``).
+
         Args:
           refresh_every: binary-AM refresh cadence inside the epoch scan
             (1 = per batch; larger trades fidelity for fewer
@@ -202,91 +208,98 @@ class MemhdModel:
         rates and (optional) eval accuracies — consumed by the Fig.-5/6
         benchmarks.
         """
-        epochs = self.am_cfg.epochs if epochs is None else epochs
-        if noise_sim is not None and mode != "batched":
-            raise ValueError("noise_sim needs the batched scan engine")
-        if cell_bits is not None and mode != "batched":
-            raise ValueError("cell_bits needs the batched scan engine")
+        with span("fit"):
+            epochs = self.am_cfg.epochs if epochs is None else epochs
+            if noise_sim is not None and mode != "batched":
+                raise ValueError("noise_sim needs the batched scan engine")
+            if cell_bits is not None and mode != "batched":
+                raise ValueError("cell_bits needs the batched scan engine")
 
-        # Encode once; init and every epoch share these buffers.
-        h = self.encode(feats)
-        q = encoding.binarize_query(h)
+            # Encode once; init and every epoch share these buffers.
+            with span("fit.encode"):
+                h = self.encode(feats)
+                q = encoding.binarize_query(h)
+                if mode == "batched":
+                    n = h.shape[0]
+                    hb, qb, yb, mask = qail.prebatch(h, q, labels,
+                                                     self.am_cfg.batch_size)
 
-        start_epoch = 0
-        init_hist: List[dict] = []
-        curve: List[dict] = []
-        state = None
-        resumed = False
-        if ckpt is not None:
-            template = MemhdTrainState.create(self.am_state)
-            step, tree, extra = ckpt.restore(template)
-            if step is not None:
-                state = jax.tree.map(jnp.asarray, tree.am_state)
-                start_epoch = step
-                curve = list(extra.get("curve", []))
-                init_hist = list(extra.get("init", []))
-                resumed = True
-                log.info("fit resumed from epoch %d", start_epoch)
-
-        if state is None:
-            if init_method == "keep":
-                model, init_hist = self, []
-                state = self.am_state
-            else:
-                model, init_hist = self.initialize_am(
-                    key, feats, labels, method=init_method, h=h, q=q)
-                state = model.am_state
-        else:
-            model = dataclasses.replace(self, am_state=state)
-
-        eval_q = (model.encode_query(eval_feats)
-                  if eval_feats is not None else None)
-
-        def _save(ep, st):
+            start_epoch = 0
+            init_hist: List[dict] = []
+            curve: List[dict] = []
+            state = None
+            resumed = False
             if ckpt is not None:
-                ckpt.save(ep, MemhdTrainState.create(st, ep),
-                          extra={"curve": curve, "init": init_hist})
+                template = MemhdTrainState.create(self.am_state)
+                step, tree, extra = ckpt.restore(template)
+                if step is not None:
+                    state = jax.tree.map(jnp.asarray, tree.am_state)
+                    start_epoch = step
+                    curve = list(extra.get("curve", []))
+                    init_hist = list(extra.get("init", []))
+                    resumed = True
+                    log.info("fit resumed from epoch %d", start_epoch)
 
-        if start_epoch == 0 and not resumed:
-            if eval_q is not None:
-                acc0 = qail.evaluate(state, eval_q, eval_labels)
-                curve.append({"epoch": 0, "eval_acc": acc0})
-            _save(0, state)
-
-        if mode == "batched":
-            n = h.shape[0]
-            hb, qb, yb, mask = qail.prebatch(h, q, labels,
-                                             self.am_cfg.batch_size)
-        noise_base = None
-        if noise_sim is not None:
-            from repro.imcsim import device as device_lib
-            noise_base = (device_lib.device_instance_key(noise_sim)
-                          if noise_mode == "fixed"
-                          else jax.random.key(noise_sim.seed))
-        for ep in range(start_epoch + 1, epochs + 1):
-            if mode == "sequential":
-                state = qail.qail_epoch_sequential(
-                    state, self.am_cfg, h, q, labels)
-                miss = float("nan")
+            if state is None:
+                if init_method == "keep":
+                    model, init_hist = self, []
+                    state = self.am_state
+                else:
+                    model, init_hist = self.initialize_am(
+                        key, feats, labels, method=init_method, h=h, q=q)
+                    state = model.am_state
             else:
-                nkey = None
-                if noise_base is not None:
-                    nkey = (noise_base if noise_mode == "fixed"
-                            else jax.random.fold_in(noise_base, ep))
-                state, n_miss = qail.qail_epoch_scan(
-                    state, self.am_cfg, hb, qb, yb, mask,
-                    refresh_every=refresh_every, use_kernel=use_kernel,
-                    sim=noise_sim, noise_key=nkey, noise_mode=noise_mode,
-                    cell_bits=cell_bits)
-                miss = float(n_miss) / n  # the ONE host sync this epoch
-            rec = {"epoch": ep, "train_miss": miss}
-            if eval_q is not None:
-                rec["eval_acc"] = qail.evaluate(state, eval_q, eval_labels)
-            curve.append(rec)
-            if ep % ckpt_every == 0 or ep == epochs:
-                _save(ep, state)
-        model = dataclasses.replace(model, am_state=state)
-        return model, {"init": init_hist, "curve": curve}
+                model = dataclasses.replace(self, am_state=state)
+
+            eval_q = (model.encode_query(eval_feats)
+                      if eval_feats is not None else None)
+
+            def _save(ep, st):
+                if ckpt is not None:
+                    ckpt.save(ep, MemhdTrainState.create(st, ep),
+                              extra={"curve": curve, "init": init_hist})
+
+            if start_epoch == 0 and not resumed:
+                if eval_q is not None:
+                    acc0 = qail.evaluate(state, eval_q, eval_labels)
+                    curve.append({"epoch": 0, "eval_acc": acc0})
+                _save(0, state)
+
+            noise_base = None
+            if noise_sim is not None:
+                from repro.imcsim import device as device_lib
+                noise_base = (device_lib.device_instance_key(noise_sim)
+                              if noise_mode == "fixed"
+                              else jax.random.key(noise_sim.seed))
+            for ep in range(start_epoch + 1, epochs + 1):
+                if mode == "sequential":
+                    with span("fit.epoch", epoch=ep):
+                        state = qail.qail_epoch_sequential(
+                            state, self.am_cfg, h, q, labels)
+                    miss = float("nan")
+                else:
+                    nkey = None
+                    if noise_base is not None:
+                        nkey = (noise_base if noise_mode == "fixed"
+                                else jax.random.fold_in(noise_base, ep))
+                    with span("fit.epoch", epoch=ep):
+                        state, n_miss = qail.qail_epoch_scan(
+                            state, self.am_cfg, hb, qb, yb, mask,
+                            refresh_every=refresh_every,
+                            use_kernel=use_kernel, sim=noise_sim,
+                            noise_key=nkey, noise_mode=noise_mode,
+                            cell_bits=cell_bits)
+                    with span("fit.sync", epoch=ep):
+                        # The ONE host sync this epoch.
+                        miss = float(n_miss) / n
+                rec = {"epoch": ep, "train_miss": miss}
+                if eval_q is not None:
+                    rec["eval_acc"] = qail.evaluate(state, eval_q, eval_labels)
+                curve.append(rec)
+                if ep % ckpt_every == 0 or ep == epochs:
+                    _save(ep, state)
+            model = dataclasses.replace(model, am_state=state)
+            return model, {"init": init_hist, "curve": curve}
 
     def fit_sharded(self, key: Array, feats: Array, labels: Array,
                     *, mesh=None, epochs: Optional[int] = None,

@@ -143,6 +143,7 @@ def am_search(q: Array, am_t: Array, *, block_b: int = 256,
             pltpu.VMEM((bb,), jnp.float32),
             pltpu.VMEM((bb,), jnp.int32),
         ],
+        name="am_search",
         interpret=interpret,
     )(qp, ap)
     return idx[:b, 0], sim[:b, 0]

@@ -180,6 +180,7 @@ def am_search_imc(q: Array, am_t: Array, offsets: Array | None = None, *,
             pltpu.VMEM((bb,), jnp.float32),
             pltpu.VMEM((bb,), jnp.int32),
         ],
+        name="am_search_imc",
         interpret=interpret,
     )(qp, ap, offsets.astype(jnp.float32).reshape(-1))
     return idx[:b, 0], sim[:b, 0]

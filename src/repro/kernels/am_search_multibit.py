@@ -221,6 +221,7 @@ def am_search_multibit(q: Array, am_planes_t: Array,
             pltpu.VMEM((bb,), jnp.float32),
             pltpu.VMEM((bb,), jnp.int32),
         ],
+        name="am_search_multibit",
         interpret=interpret,
     )(qp, ap, offsets.astype(jnp.float32).reshape(-1))
     return idx[:b, 0], sim[:b, 0]

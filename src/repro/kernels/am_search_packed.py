@@ -254,6 +254,7 @@ def am_search_packed(q_packed: Array, am_packed_t: Array, *,
             pltpu.VMEM((bb,), jnp.float32),
             pltpu.VMEM((bb,), jnp.int32),
         ],
+        name="am_search_packed",
         interpret=interpret,
     )(qp, ap)
     return idx[:b, 0], sim[:b, 0]

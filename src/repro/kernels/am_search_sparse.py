@@ -211,6 +211,7 @@ def am_search_sparse_gathered(q_packed: Array, tiles_packed: Array,
         # (double-buffered), unlike the flat kernel's shared AM block:
         # past bB = 256 at P = 128 they outgrow the 16 MiB default.
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 << 20),
+        name="am_search_sparse_gathered",
         interpret=interpret,
     )(qp, tp, ip)
     return idx[:b], sim[:b]

@@ -184,6 +184,7 @@ def am_shortlist(q_packed: Array, super_packed_t: Array, *,
             pltpu.VMEM((bb, s), jnp.float32),
             pltpu.VMEM((bb, s), jnp.int32),
         ],
+        name="am_shortlist",
         interpret=interpret,
     )(qp, ap)
     return idx[:b], sim[:b]

@@ -80,6 +80,7 @@ def binary_mvm(x: Array, w: Array, *, block_b: int = 128,
         out_specs=pl.BlockSpec((bb, TILE), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((xp.shape[0], wp.shape[1]),
                                        jnp.float32),
+        name="binary_mvm",
         interpret=interpret,
     )(xp, wp)
     return out[:b, :n]

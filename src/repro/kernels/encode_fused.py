@@ -141,6 +141,7 @@ def encode_pack(feats: Array, projection: Array, *,
         out_shape=jax.ShapeDtypeStruct((xp.shape[0], wp.shape[1] // 8),
                                        jnp.uint8),
         scratch_shapes=[pltpu.VMEM((bb, td), jnp.float32)],
+        name="encode_pack",
         interpret=interpret,
     )(xp, wp)
     return out[:b, : -(-d // 8)]

@@ -113,5 +113,6 @@ def flash_decode(q: Array, k_cache: Array, v_cache: Array,
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((1, dh), jnp.float32),
         ],
+        name="flash_decode",
         interpret=interpret,
     )(cache_len, q, kp, vp)

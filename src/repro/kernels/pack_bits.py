@@ -86,6 +86,7 @@ def pack_bits(x: Array, *, block_r: int = 256,
         out_specs=pl.BlockSpec((br, LANES // 8), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((xp.shape[0], xp.shape[1] // 8),
                                        jnp.uint8),
+        name="pack_bits",
         interpret=interpret,
     )(xp)
     return out[:r, : c // 8]
@@ -109,6 +110,7 @@ def unpack_bits(packed: Array, *, block_r: int = 256,
         out_specs=pl.BlockSpec((br, LANES), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((pp.shape[0], pp.shape[1] * 8),
                                        jnp.float32),
+        name="unpack_bits",
         interpret=interpret,
     )(pp)
     return out[:r, : cb * 8]

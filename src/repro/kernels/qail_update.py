@@ -172,6 +172,7 @@ def qail_update(q: Array, upd: Array, am_t: Array, centroid_class: Array,
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(bb, dd + pd, c + pc)),
+        name="qail_update",
         interpret=interpret,
     )(qp, up, ap, ownp, yp, mp)
     return delta[:c, :dd], miss[0, 0]

@@ -113,6 +113,7 @@ def ssd_chunk(x: Array, b: Array, c: Array, dt: Array, da: Array,
             jax.ShapeDtypeStruct(x.shape, x.dtype),
             jax.ShapeDtypeStruct(state.shape, jnp.float32),
         ],
+        name="ssd_chunk",
         interpret=interpret,
     )(x, b, c, dt, da, state)
     return y, s_new

@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import itertools
 import json
 import logging
 import math
@@ -54,6 +55,10 @@ from repro.obs import span
 log = logging.getLogger("serve_memhd")
 
 TILE_B = 8  # batch padding granularity (float32 sublane tile)
+
+# Batch sequence numbers, unique over the process's serve_batches calls:
+# every span of a batch carries its number as ``batch=``.
+_BATCH_IDS = itertools.count()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -124,8 +129,10 @@ def serve_batches(deployed, requests: Sequence[Request],
     every latency field (JSON ``null``) — no fabricated zero rows.
 
     Each batch also emits host spans (``host_prep`` / ``pad`` /
-    ``dispatch`` / ``device_wait``, exportable as a Chrome trace via
-    ``repro.obs``) and feeds the ``serve_batch_ms`` histogram /
+    ``dispatch`` / ``device_wait``), all carrying the batch's sequence
+    number as ``batch=``, and the call a ``stats`` span around its
+    summary; all are exportable as a Chrome trace via ``repro.obs``.
+    The call feeds the ``serve_batch_ms`` histogram /
     ``serve_rows_total`` counters of the default metrics registry.
 
     ``topk >= 1`` serves through the backend's ``predict_topk`` — the
@@ -166,7 +173,7 @@ def serve_batches(deployed, requests: Sequence[Request],
     queue_ms: List[float] = []
     service_ms: List[float] = []
     rows_real = rows_padded = 0
-    inflight: deque = deque()  # (idx, batch, n_valid, result, t_disp)
+    inflight: deque = deque()  # (seq, batch, n_valid, result, t_disp)
     last_ready = [float("-inf")]  # when the device finished batch k-1
     hist = obs.histogram(
         "serve_batch_ms", "per-batch serving latency by stage")
@@ -176,8 +183,8 @@ def serve_batches(deployed, requests: Sequence[Request],
                               "classification requests served")
 
     def _drain_one():
-        idx, batch, n_valid, fut, t_disp = inflight.popleft()
-        with span("device_wait", batch=idx):
+        seq, batch, n_valid, fut, t_disp = inflight.popleft()
+        with span("device_wait", batch=seq):
             jax.block_until_ready(fut)
         t_ready = time.perf_counter()
         # The batch could only start once everything dispatched before
@@ -199,35 +206,39 @@ def serve_batches(deployed, requests: Sequence[Request],
             responses[r.rid] = pred[ofs:ofs + r.size]
             ofs += r.size
 
-    for i, batch in enumerate(batches):
+    for batch in batches:
+        seq = next(_BATCH_IDS)
         # Host-side prep of batch k+1 overlaps device work on batch k.
-        with span("host_prep", batch=i, requests=len(batch)):
+        with span("host_prep", batch=seq, requests=len(batch)):
             feats = np.concatenate([r.feats for r in batch])
-            with span("pad", batch=i):
+            with span("pad", batch=seq):
                 padded, n_valid = pad_to_multiple(feats, tile)
         rows_real += n_valid
         rows_padded += padded.shape[0]
         t0 = time.perf_counter()
-        with span("dispatch", batch=i, rows=padded.shape[0]):
+        with span("dispatch", batch=seq, rows=padded.shape[0]):
             fut = predict(padded)
-        inflight.append((i, batch, n_valid, fut, t0))
+        inflight.append((seq, batch, n_valid, fut, t0))
         while len(inflight) >= depth:
             _drain_one()
     while inflight:
         _drain_one()
-    served_rows.inc(rows_real)
-    served_reqs.inc(len(requests))
-    stats = {
-        "depth": depth,
-        "batches": len(batches),
-        "rows_real": rows_real,
-        "rows_padded": rows_padded,
-        "pad_overhead": (round(rows_padded / rows_real - 1, 3)
-                         if rows_real else None),
-        **_lat_fields("lat_ms", lat_ms),
-        **_lat_fields("service_ms", service_ms),
-        **_lat_fields("queue_ms", queue_ms),
-    }
+    # The call's summary is host work between its last batch and the
+    # caller's next call, while the device waits.
+    with span("stats", batches=len(batches)):
+        served_rows.inc(rows_real)
+        served_reqs.inc(len(requests))
+        stats = {
+            "depth": depth,
+            "batches": len(batches),
+            "rows_real": rows_real,
+            "rows_padded": rows_padded,
+            "pad_overhead": (round(rows_padded / rows_real - 1, 3)
+                             if rows_real else None),
+            **_lat_fields("lat_ms", lat_ms),
+            **_lat_fields("service_ms", service_ms),
+            **_lat_fields("queue_ms", queue_ms),
+        }
     return responses, stats
 
 

@@ -3,10 +3,10 @@
 Three windows into the runtime the rest of the repo can't see from
 wall clocks alone:
 
-  * **Compile/recompile counting.** ``install()`` registers
-    ``jax.monitoring`` listeners; every XLA backend compile increments
-    ``jax_compiles_total`` (and feeds ``jax_compile_seconds``), every
-    trace/lowering duration event lands in a labeled counter. A cached
+  * **Compile/recompile counting.** ``install()`` registers a
+    ``jax.monitoring`` duration listener; every XLA backend compile
+    increments ``jax_compiles_total`` (and feeds ``jax_compile_seconds``
+    when its duration is finite and non-negative). A cached
     executable fires no event, so the counter's *delta* over a window
     is exactly the number of fresh compilations in that window — the
     basis of ``assert_no_recompiles`` and the serving driver's
@@ -28,6 +28,7 @@ so one process-lifetime registration is the contract.
 """
 from __future__ import annotations
 
+import math
 import threading
 from contextlib import contextmanager
 from typing import Dict, Optional
@@ -48,37 +49,39 @@ class RecompileError(AssertionError):
     """A region that must be shape-stable recompiled anyway."""
 
 
+def _compile_instruments():
+    return (_metrics.counter(
+                "jax_compiles_total",
+                "XLA backend compilations (cache hits fire no event)"),
+            _metrics.histogram(
+                "jax_compile_seconds", "XLA backend compile durations",
+                buckets=_COMPILE_BUCKETS))
+
+
+def _on_duration(name: str, dur: float, **kw) -> None:
+    """The ``jax.monitoring`` duration listener: counts every backend
+    compile, and times those whose duration is a finite non-negative
+    number. JAX times compiles on the wall clock, which can step back,
+    so a duration may read negative; a listener that raised would end
+    the process, so this one never does."""
+    if name != COMPILE_EVENT:
+        return
+    compiles, compile_secs = _compile_instruments()
+    compiles.inc()
+    if math.isfinite(dur) and dur >= 0:
+        compile_secs.observe(dur)
+
+
 def install() -> None:
-    """Register the jax.monitoring listeners (once per process)."""
+    """Register the jax.monitoring compile listener (once per process)."""
     global _installed
     with _install_lock:
         if _installed:
             return
         from jax import monitoring
 
-        compiles = _metrics.counter(
-            "jax_compiles_total",
-            "XLA backend compilations (cache hits fire no event)")
-        compile_secs = _metrics.histogram(
-            "jax_compile_seconds", "XLA backend compile durations",
-            buckets=_COMPILE_BUCKETS)
-        durations = _metrics.counter(
-            "jax_event_duration_seconds_total",
-            "summed jax.monitoring duration events by event name")
-        events = _metrics.counter(
-            "jax_events_total", "jax.monitoring point events by name")
-
-        def on_duration(name: str, dur: float, **kw) -> None:
-            durations.inc(dur, event=name)
-            if name == COMPILE_EVENT:
-                compiles.inc()
-                compile_secs.observe(dur)
-
-        def on_event(name: str, **kw) -> None:
-            events.inc(event=name)
-
-        monitoring.register_event_duration_secs_listener(on_duration)
-        monitoring.register_event_listener(on_event)
+        _compile_instruments()
+        monitoring.register_event_duration_secs_listener(_on_duration)
         _installed = True
 
 

@@ -1,21 +1,28 @@
-"""Nested host-side span tracing with Chrome trace-event export.
+"""Nested host-side span tracing, on the profiler's clock, with Chrome
+trace-event export.
 
-``with span("pad"): ...`` records a complete event ("ph": "X") with
-``perf_counter_ns`` timestamps; spans nest through a thread-local
+``with span("pad", batch=3): ...`` records a complete event ("ph": "X")
+with ``perf_counter_ns`` timestamps; spans nest through a thread-local
 stack, so every event carries its own ``span_id`` and its enclosing
 ``parent_id`` — the double-buffered serving loop's host-prep of batch
 k+1 visibly overlaps batch k's device wait when the export is opened
 in Perfetto (https://ui.perfetto.dev) or chrome://tracing.
 
-``span(..., device=True)`` additionally wraps the body in
-``jax.profiler.TraceAnnotation``, so when a device profile is being
-captured the host span lines up with the XLA activity it caused; off
-the profiler the annotation is a cheap no-op, and the bridge degrades
-to nothing if the profiler API is unavailable.
+Every span also enters ``jax.profiler.TraceAnnotation`` under its plain
+name (the arguments stay out of the TraceMe name), so while a device
+profile is being captured the span sits on the Python thread's line of
+the profiler's own trace, on the profiler's clock, beside the XLA
+activity it caused. Off the profiler the annotation is a sub-microsecond
+no-op. The annotation class is resolved on the first span, so importing
+this module stays free of jax.
 
-The recorder is bounded (``max_events``, default 100k): a long-running
-serving process must not grow a trace without limit, so past the cap
-new events are counted in ``dropped`` instead of stored.
+With ``enabled = False`` a span is a shared no-op context: it records
+nothing, allocates nothing and enters no annotation.
+
+The recorder is bounded (``max_events``, default 200k, about 55 MB of
+events): a long-running serving process must not grow a trace without
+limit, so past the cap new events are counted in ``dropped`` instead of
+stored.
 """
 from __future__ import annotations
 
@@ -24,31 +31,103 @@ import json
 import os
 import threading
 import time
-from contextlib import contextmanager
 from typing import Dict, List, Optional
+
+# ``jax.profiler.TraceAnnotation``, resolved on the first span; False
+# where the profiler API cannot be imported.
+_ANNOTATION = None
+
+
+def _annotation_cls():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
 
 
 class SpanEvent:
-    """One completed span (Chrome "X" event), times in ns."""
+    """One span: the context manager while its body runs, then the
+    recorded event (Chrome "X" event), times in ns.
+
+    The arguments are kept as one flat tuple (keys, then values) rather
+    than a dict per event; ``args`` rebuilds the dict on demand.
+    """
 
     __slots__ = ("name", "start_ns", "dur_ns", "span_id", "parent_id",
-                 "tid", "args")
+                 "tid", "_args", "_tracer", "_ann")
 
-    def __init__(self, name, start_ns, dur_ns, span_id, parent_id, tid,
-                 args):
+    def __init__(self, tracer: "Tracer", name: str, args: Dict):
         self.name = name
-        self.start_ns = start_ns
-        self.dur_ns = dur_ns
-        self.span_id = span_id
-        self.parent_id = parent_id
-        self.tid = tid
-        self.args = args
+        self._args = (*args, *args.values()) if args else None
+        self._tracer = tracer
+
+    @property
+    def args(self) -> Optional[Dict]:
+        a = self._args
+        if not a:
+            return None
+        n = len(a) // 2
+        return dict(zip(a[:n], a[n:]))
+
+    def __enter__(self):
+        tr = self._tracer
+        loc = tr._local
+        try:
+            stack = loc.stack
+        except AttributeError:
+            stack = loc.stack = []
+            loc.tid = threading.get_ident()
+        self.tid = loc.tid
+        self.span_id = span_id = next(tr._ids)
+        self.parent_id = stack[-1] if stack else 0
+        stack.append(span_id)
+        cls = _ANNOTATION if _ANNOTATION is not None else _annotation_cls()
+        if cls:
+            ann = self._ann = cls(self.name)
+            ann.__enter__()
+        else:
+            self._ann = None
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self.dur_ns = time.perf_counter_ns() - self.start_ns
+        ann = self._ann
+        if ann is not None:
+            ann.__exit__(*exc)
+            self._ann = None
+        tr = self._tracer
+        self._tracer = None
+        tr._local.stack.pop()
+        with tr._lock:
+            if len(tr._events) < tr.max_events:
+                tr._events.append(self)
+            else:
+                tr.dropped += 1
+        return False
+
+
+class _NullSpan:
+    __slots__ = ()
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NULL_SPAN = _NullSpan()
 
 
 class Tracer:
     """Span recorder; one per process is plenty (module ``TRACER``)."""
 
-    def __init__(self, max_events: int = 100_000):
+    def __init__(self, max_events: int = 200_000):
         self.max_events = max_events
         self._lock = threading.Lock()
         self._events: List[SpanEvent] = []
@@ -59,50 +138,20 @@ class Tracer:
 
     # -- recording ----------------------------------------------------
 
-    def _stack(self) -> List[int]:
-        st = getattr(self._local, "stack", None)
-        if st is None:
-            st = self._local.stack = []
-        return st
-
-    @contextmanager
-    def span(self, name: str, device: bool = False, **args):
-        """Record a nested span around the body.
+    def span(self, name: str, **args):
+        """A context manager recording a nested span around its body.
 
         ``args`` become the event's Chrome-trace ``args`` (stringified
-        lazily at export). ``device=True`` bridges to
-        ``jax.profiler.TraceAnnotation(name)`` so host spans align with
-        XLA device activity under an active profiler capture.
+        lazily at export); the profiler annotation carries the name
+        alone.
         """
         if not self.enabled:
-            yield
-            return
-        stack = self._stack()
-        span_id = next(self._ids)
-        parent_id = stack[-1] if stack else 0
-        stack.append(span_id)
-        annotation = _device_annotation(name) if device else None
-        start = time.perf_counter_ns()
-        try:
-            if annotation is not None:
-                with annotation:
-                    yield
-            else:
-                yield
-        finally:
-            dur = time.perf_counter_ns() - start
-            stack.pop()
-            ev = SpanEvent(name, start, dur, span_id, parent_id,
-                           threading.get_ident(), args or None)
-            with self._lock:
-                if len(self._events) < self.max_events:
-                    self._events.append(ev)
-                else:
-                    self.dropped += 1
+            return _NULL_SPAN
+        return SpanEvent(self, name, args)
 
     def current_span_id(self) -> int:
         """Id of the innermost open span on this thread (0 = none)."""
-        stack = self._stack()
+        stack = getattr(self._local, "stack", None)
         return stack[-1] if stack else 0
 
     # -- export -------------------------------------------------------
@@ -156,15 +205,6 @@ class Tracer:
 
 def _jsonable(v):
     return v if isinstance(v, (int, float, bool, str, type(None))) else str(v)
-
-
-def _device_annotation(name: str):
-    """``jax.profiler.TraceAnnotation`` when available, else None."""
-    try:
-        from jax.profiler import TraceAnnotation
-    except Exception:  # profiler API absent/changed: degrade silently
-        return None
-    return TraceAnnotation(name)
 
 
 # Process-default tracer; ``span`` is the one-liner call sites use.

@@ -41,6 +41,7 @@ that must stay 0.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import logging
 import math
 import time
@@ -155,6 +156,7 @@ class _Inflight:
     t_dispatch: float
     generation: int
     bucket: int
+    seq: int
 
 
 class OnlineEngine:
@@ -202,6 +204,7 @@ class OnlineEngine:
         self.responses: Dict[int, np.ndarray] = {}
         self.request_lat_ms: Dict[int, float] = {}
         self._inflight: deque = deque()
+        self._batch_ids = itertools.count()  # ``batch=`` of a batch's spans
         self._feature_spec = None  # (n_features, dtype) after first batch
         self._t0 = None
         self._last_ready = 0.0
@@ -276,12 +279,13 @@ class OnlineEngine:
 
     # -- dispatch / drain ------------------------------------------------------
     def _dispatch(self, requests: List[OnlineRequest]) -> None:
-        with span("host_prep", requests=len(requests)):
+        seq = next(self._batch_ids)
+        with span("host_prep", batch=seq, requests=len(requests)):
             feats = (requests[0].feats if len(requests) == 1 else
                      np.concatenate([r.feats for r in requests]))
             rows = feats.shape[0]
             bucket = self._bucket_for(rows)
-            with span("pad", rows=rows, bucket=bucket):
+            with span("pad", batch=seq, rows=rows, bucket=bucket):
                 padded = np.zeros((bucket,) + feats.shape[1:],
                                   feats.dtype)
                 padded[:rows] = feats
@@ -289,16 +293,21 @@ class OnlineEngine:
         self._batch_rows.append(rows)
         self._gauge_q.set(len(self.queue))
         t_disp = self._clock()
-        with span("dispatch", rows=bucket):
+        # Each request's wait: arrival -> dispatch on the engine's clock,
+        # the planner's hold plus the host's own delay.
+        waits = [t_disp - r.t_arrival for r in requests]
+        with span("dispatch", batch=seq, rows=bucket,
+                  requests=len(requests), wait_ms_sum=sum(waits) * 1e3,
+                  wait_ms_max=max(waits) * 1e3):
             fut = self._predict(padded)
         self._inflight.append(_Inflight(
             requests=requests, n_valid=rows, future=fut,
             t_dispatch=t_disp, generation=self.updater.generation,
-            bucket=bucket))
+            bucket=bucket, seq=seq))
 
     def _drain_one(self) -> None:
         f: _Inflight = self._inflight.popleft()
-        with span("device_wait", rows=f.bucket):
+        with span("device_wait", batch=f.seq, rows=f.bucket):
             jax.block_until_ready(f.future)
         t_ready = self._clock()
         service = t_ready - max(f.t_dispatch, self._last_ready)

@@ -212,11 +212,62 @@ class TestTrace:
             pass
         assert tr.events() == []
 
-    def test_device_bridge_is_noop_safe(self):
+    def test_device_bridge_is_noop_safe(self, monkeypatch):
+        """Every span enters a profiler annotation of its plain name
+        (arguments stay out of it); a disabled tracer enters none and
+        hands out one shared no-op context."""
+        from repro.obs import trace
+        entered = []
+
+        class Annotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                entered.append(self.name)
+
+            def __exit__(self, *exc):
+                entered.append("/" + self.name)
+
+        monkeypatch.setattr(trace, "_ANNOTATION", Annotation)
         tr = Tracer()
-        with tr.span("annotated", device=True):
-            jnp.ones((4,)).block_until_ready()
-        assert [e.name for e in tr.events()] == ["annotated"]
+        with tr.span("annotated", batch=3):
+            with tr.span("inner"):
+                jnp.ones((4,)).block_until_ready()
+        assert entered == ["annotated", "inner", "/inner", "/annotated"]
+        assert [e.name for e in tr.events()] == ["inner", "annotated"]
+        assert tr.events()[1].args == {"batch": 3}
+        tr.enabled = False
+        entered.clear()
+        off = tr.span("annotated", batch=4)
+        assert off is tr.span("other")
+        with off:
+            pass
+        assert entered == [] and len(tr.events()) == 2
+
+    def test_span_on_profiler_clock(self, tmp_path):
+        """Under an active profile the span is a host event of the
+        trace, on the Python thread's line, under its plain name."""
+        from jax.profiler import ProfileData
+        tr = Tracer()
+        jnp.ones((4,)).block_until_ready()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with jax.profiler.TraceAnnotation("bench.window"):
+                with tr.span("dispatch", batch=7):
+                    jnp.ones((8,)).block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        [path] = list(tmp_path.rglob("*.xplane.pb"))
+        lines = [[e.name for e in line.events] for p in
+                 ProfileData.from_file(str(path)).planes
+                 if p.name == "/host:CPU" for line in p.lines]
+        line = next(ln for ln in lines if "bench.window" in ln)
+        assert "dispatch" in line
+        assert not any(n.startswith("dispatch#") for ln in lines
+                       for n in ln)
+        [ev] = tr.events()
+        assert ev.args == {"batch": 7}
 
 
 # ----------------------------------------------------------------- jaxmon
@@ -259,13 +310,30 @@ class TestJaxmon:
     def test_install_idempotent(self):
         obs.install()
         before = jaxmon.compiles()
+        timed = obs.snapshot()["jax_compile_seconds"]["values"]
+        timed = timed[""]["count"] if timed else 0
         obs.install()  # second install must not double-register
         jax.jit(lambda x: x - 3.0)(jnp.ones((5,))).block_until_ready()
         delta = jaxmon.compiles() - before
         assert delta >= 1
-        # One listener: the compile histogram count matches the counter.
+        # One listener: the compile histogram counts as the counter does.
         snap = obs.snapshot()["jax_compile_seconds"]["values"][""]
-        assert snap["count"] == jaxmon.compiles()
+        assert snap["count"] - timed == delta
+
+    @pytest.mark.parametrize("dur", [-0.25, float("nan"), float("inf")])
+    def test_bad_compile_duration_never_raises(self, dur):
+        """JAX times compiles on the wall clock: a negative (or
+        non-finite) duration is counted as a compile, left out of the
+        compile-time histogram, and never raises."""
+        obs.install()
+        before = jaxmon.compiles()
+        timed = obs.snapshot()["jax_compile_seconds"]["values"]
+        timed = timed[""]["count"] if timed else 0
+        jaxmon._on_duration(jaxmon.COMPILE_EVENT, dur)
+        jaxmon._on_duration("/jax/core/compile/jaxpr_trace_duration", dur)
+        assert jaxmon.compiles() == before + 1
+        snap = obs.snapshot()["jax_compile_seconds"]["values"]
+        assert (snap[""]["count"] if snap else 0) == timed
 
     def test_memory_gauges_handle_absent_stats(self):
         # CPU devices report no allocator stats: no gauges, no crash.

@@ -353,6 +353,40 @@ class TestOnlineEngine:
                 eng.responses[ev.request.rid],
                 np.asarray(dep.predict(ev.request.feats)))
 
+    def test_dispatch_spans_carry_queue_wait(self, ds, full_model):
+        """The ``dispatch`` spans' ``wait_ms_sum`` over ``requests`` is
+        the mean arrival -> dispatch wait of the served requests, and
+        every span of a batch carries the batch's sequence number."""
+        eng = self._engine(full_model, depth=2)
+        evs = poisson_arrivals(np.asarray(ds.test_x), n_requests=30,
+                               rate_qps=3000, max_size=6,
+                               deadline_ms=10.0, seed=12)
+        waits = []
+        drain = eng._drain_one
+
+        def spy():
+            f = eng._inflight[0]
+            waits.extend(f.t_dispatch - r.t_arrival for r in f.requests)
+            drain()
+
+        eng._drain_one = spy
+        obs.TRACER.reset()
+        eng.serve(evs)
+        spans = obs.TRACER.events()
+        disp = [e.args for e in spans if e.name == "dispatch"]
+        assert sum(a["requests"] for a in disp) == len(waits) == 30
+        assert (sum(a["wait_ms_sum"] for a in disp) / 30
+                == pytest.approx(np.mean(waits) * 1e3))
+        assert (max(a["wait_ms_max"] for a in disp)
+                == pytest.approx(max(waits) * 1e3))
+        batch_spans = {}
+        for e in spans:
+            batch_spans.setdefault(e.args["batch"], []).append(e.name)
+        assert len(batch_spans) == len(disp) > 1
+        for names in batch_spans.values():
+            assert sorted(names) == ["device_wait", "dispatch",
+                                     "host_prep", "pad"]
+
     def test_shape_stable_swap_zero_recompiles(self, ds, full_model):
         """Tentpole contract: a mid-stream drift fold swaps the model
         with ZERO steady-state recompiles — every compile in the run

@@ -188,6 +188,33 @@ class TestEncodeOnce:
         assert calls["n"] == 1  # init + every epoch share ONE encode
 
 
+class TestFitSpans:
+    def test_fit_spans_nest(self, small_hdc_data):
+        """A 3-epoch fit: one ``fit`` holding one ``fit.encode`` and,
+        each epoch, a ``fit.epoch`` dispatch then its ``fit.sync``."""
+        from repro import obs
+        ds = small_hdc_data
+        enc = EncoderConfig(kind="projection", features=ds.features,
+                            dim=128)
+        amc = MemhdConfig(dim=128, columns=32, classes=ds.classes,
+                          epochs=3, kmeans_iters=2, batch_size=128)
+        m = MemhdModel.create(jax.random.key(0), enc, amc)
+        obs.TRACER.reset()
+        m.fit(jax.random.key(1), ds.train_x, ds.train_y)
+        evs = [e for e in obs.TRACER.events() if e.name.startswith("fit")]
+        [fit] = [e for e in evs if e.name == "fit"]
+        kids = [e for e in evs if e is not fit]
+        assert all(e.parent_id == fit.span_id for e in kids)
+        assert [(e.name, (e.args or {}).get("epoch")) for e in kids] == [
+            ("fit.encode", None), ("fit.epoch", 1), ("fit.sync", 1),
+            ("fit.epoch", 2), ("fit.sync", 2), ("fit.epoch", 3),
+            ("fit.sync", 3)]
+        ends = [e.start_ns + e.dur_ns for e in kids]
+        assert all(fit.start_ns <= e.start_ns for e in kids)
+        assert max(ends) <= fit.start_ns + fit.dur_ns
+        assert all(a <= b.start_ns for a, b in zip(ends, kids[1:]))
+
+
 class TestCheckpointedFit:
     def test_resume_is_bit_exact(self, small_hdc_data, tmp_path):
         from repro.checkpoint import CheckpointConfig, CheckpointManager

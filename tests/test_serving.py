@@ -402,3 +402,27 @@ class TestObsIntegration:
         for p in pads:
             parent = by_id[p["args"]["parent_id"]]
             assert parent["name"] == "host_prep"
+
+    def test_batch_spans_share_a_sequence_number(self, served):
+        """Every span of a batch carries the batch's sequence number as
+        ``batch=``; the numbers run on across calls, and each call ends
+        in one ``stats`` span."""
+        ds, _, dep = served
+        reqs = synthetic_requests(np.asarray(ds.test_x), n_requests=12,
+                                  max_size=5, seed=9)
+        obs.TRACER.reset()
+        _, first = serve_batches(dep, reqs, max_batch=16, depth=2)
+        _, second = serve_batches(dep, reqs, max_batch=16, depth=2)
+        evs = obs.TRACER.events()
+        assert [e.args for e in evs if e.name == "stats"] == [
+            {"batches": first["batches"]}, {"batches": second["batches"]}]
+        batch_spans = {}
+        for e in evs:
+            if e.name != "stats":
+                batch_spans.setdefault(e.args["batch"], []).append(e)
+        assert len(batch_spans) == first["batches"] + second["batches"]
+        for evs in batch_spans.values():
+            assert sorted(e.name for e in evs) == [
+                "device_wait", "dispatch", "host_prep", "pad"]
+            by = {e.name: e for e in evs}
+            assert by["pad"].parent_id == by["host_prep"].span_id
