@@ -35,9 +35,6 @@ from repro.kernels.am_search_packed import imc_cycles_for as packed_search_cycle
 from repro.kernels.am_search_packed import pack_rows as _pack_rows
 from repro.kernels.am_search_sparse import am_search_sparse as _am_search_sparse
 from repro.kernels.am_search_sparse import (
-    am_search_sparse_gathered as _am_search_sparse_gathered,
-)
-from repro.kernels.am_search_sparse import (
     expand_shortlist_tiles as _expand_shortlist_tiles,
 )
 from repro.kernels.am_search_sparse import gather_shortlist as _gather_shortlist
@@ -342,18 +339,19 @@ def am_search_sparse(q_packed: Array, am_slab_t: Array, col_ids: Array,
     ``ref.am_search_sparse`` on the gathered operands, and with S = G
     the k=1 column reproduces ``am_search_packed`` bit-for-bit.
 
-    ``use_kernel=None`` (default) auto-dispatches: the Pallas kernel on
-    TPU, the bit-exact XLA gather+oracle path elsewhere. Unlike the
-    other kernels — whose inputs are shared across the grid — the
-    sparse kernel's gathered operand is per-query, so interpret-mode
-    emulation (which re-copies the full input every grid step) costs
-    O(steps x B*S*max_tiles) and is pathologically slow off-TPU; the
-    two paths are parity-tested bit-exact.
+    ``use_kernel=None`` (default) auto-dispatches. On TPU the Pallas
+    kernel reads each query's shortlisted tiles by DMA straight from
+    the resident slab (its dispatch counted with ``path=slab-dma``):
+    nothing per query is gathered in XLA. Elsewhere the bit-exact XLA
+    gather + oracle path serves (``path=xla-gather``), since the
+    kernel's per-row tile copies are slow to emulate in interpret
+    mode; the two paths are parity-tested bit-exact.
     """
     if use_kernel is None:
         use_kernel = jax.default_backend() == "tpu"
     _count("am_search_sparse", "pallas" if use_kernel else "xla-oracle",
-           B=q_packed.shape[0], D=n_dims, S=shortlist.shape[1], K=k)
+           B=q_packed.shape[0], D=n_dims, S=shortlist.shape[1], K=k,
+           path="slab-dma" if use_kernel else "xla-gather")
     if not use_kernel:
         null_tile = am_slab_t.shape[1] // 128 - 1
         tiles = _expand_shortlist_tiles(

@@ -50,6 +50,34 @@ def feats_mat(rng, b, f):
     return jnp.asarray(rng.random((b, f), dtype=np.float32))
 
 
+def sparse_parity(rng, d, assign, g, short, ks, block_b=None):
+    """Sparse fine pass over ``build_layout(AM, assign, g)``: the Pallas
+    kernel (interpret mode off-TPU) against the XLA gather + oracle
+    path, bit for bit, at every k in ``ks``. Returns the layout, the
+    expanded (B, T) tile table and the kernel's last (idx, sims)."""
+    from repro.deploy import hierarchical as hier
+    from repro.kernels.am_search_sparse import expand_shortlist_tiles
+    c, b = assign.shape[0], short.shape[0]
+    q, am = bipolar(rng, (b, d)), bipolar(rng, (c, d))
+    qp = ops.pack_rows(q)
+    layout = hier.build_layout(np.asarray(ops.pack_rows(am).T),
+                               assign.astype(np.int32), g)
+    args = (qp, jnp.asarray(layout.slab), jnp.asarray(layout.col_ids),
+            jnp.asarray(short.astype(np.int32)),
+            jnp.asarray(layout.tile_start), jnp.asarray(layout.tile_count))
+    for k in ks:
+        kw = dict(n_dims=d, k=k, max_tiles=layout.max_tiles)
+        gi, gs = ops.am_search_sparse(*args, use_kernel=True,
+                                      block_b=block_b, **kw)
+        wi, ws = ops.am_search_sparse(*args, use_kernel=False, **kw)
+        np.testing.assert_array_equal(np.asarray(gi), np.asarray(wi))
+        np.testing.assert_array_equal(np.asarray(gs), np.asarray(ws))
+    table = np.asarray(expand_shortlist_tiles(
+        args[3], args[4], args[5], max_tiles=layout.max_tiles,
+        null_tile=layout.null_tile))
+    return layout, table, (np.asarray(gi), np.asarray(gs))
+
+
 @pytest.mark.parametrize("b,f,d,c", GEOMS)
 class TestKernelOracleParity:
     """The differential sweep proper: kernel == oracle, bit for bit."""
@@ -175,29 +203,13 @@ class TestKernelOracleParity:
     def test_am_search_sparse(self, b, f, d, c):
         # Random cluster layout over the ragged C; kernel path vs the
         # gather + ref-oracle path, including k > candidate count.
-        from repro.deploy import hierarchical as hier
         rng = geom_rng(b, d, c, 7)
         g = max(1, c // 3)
-        q, am = bipolar(rng, (b, d)), bipolar(rng, (c, d))
-        qp = ops.pack_rows(q)
-        apt = np.asarray(ops.pack_rows(am).T)
         assign = rng.integers(0, g, size=c).astype(np.int32)
-        layout = hier.build_layout(apt, assign, g)
-        slab = jnp.asarray(layout.slab)
-        col_ids = jnp.asarray(layout.col_ids)
-        t_start = jnp.asarray(layout.tile_start)
-        t_count = jnp.asarray(layout.tile_count)
         s = min(2, g)
-        short = jnp.asarray(
-            np.stack([rng.permutation(g)[:s] for _ in range(b)])
-            .astype(np.int32))
-        for k in (1, min(3, c), c + 2):  # c + 2: exhausted slots
-            args = (qp, slab, col_ids, short, t_start, t_count)
-            kw = dict(n_dims=d, k=k, max_tiles=layout.max_tiles)
-            gi, gs = ops.am_search_sparse(*args, use_kernel=True, **kw)
-            wi, ws = ops.am_search_sparse(*args, use_kernel=False, **kw)
-            np.testing.assert_array_equal(np.asarray(gi), np.asarray(wi))
-            np.testing.assert_array_equal(np.asarray(gs), np.asarray(ws))
+        short = np.stack([rng.permutation(g)[:s] for _ in range(b)])
+        sparse_parity(rng, d, assign, g, short,
+                      ks=(1, min(3, c), c + 2))  # c + 2: exhausted slots
         del f
 
     def test_encode_fused(self, b, f, d, c):
@@ -328,6 +340,47 @@ class TestHierarchicalSemantics:
                 assert (gs[r, a] > gs[r, a + 1]
                         or (gs[r, a] == gs[r, a + 1]
                             and gi[r, a] < gi[r, a + 1]))
+
+    @pytest.mark.parametrize("case", ["null_slots", "few_candidates",
+                                      "disjoint_rows"])
+    def test_am_search_sparse_layouts(self, case):
+        # Layout corners of the kernel's tile reads, each against the
+        # oracle: slots that copy nothing, rows left short of k, and a
+        # block whose rows read disjoint tiles.
+        rng = geom_rng(43, len(case))
+        if case == "null_slots":
+            # One 3-tile group sets max_tiles; the 1-tile groups pad
+            # their slots with the null tile. 12 rows in blocks of 8:
+            # the padded rows of the last block read only null slots.
+            assign = np.repeat(np.arange(4), [300, 20, 60, 20])
+            short = np.stack([rng.permutation(4)[:2] for _ in range(12)])
+            layout, table, _ = sparse_parity(rng, 130, assign, 4, short,
+                                             ks=(1, 4), block_b=8)
+            assert layout.max_tiles == 3
+            assert np.mean(table == layout.null_tile) > 0.3
+        elif case == "few_candidates":
+            # Groups of 2, 3 and 4 centroids: every row's two groups
+            # hold at most 7 candidates, fewer than k = 9.
+            assign = np.repeat(np.arange(3), [2, 3, 4])
+            short = np.stack([rng.permutation(3)[:2] for _ in range(5)])
+            _, _, (idx, sims) = sparse_parity(rng, 128, assign, 3, short,
+                                              ks=(1, 9))
+            n_cand = np.bincount(assign)[short].sum(axis=1)
+            for r in range(5):
+                assert np.all(idx[r, n_cand[r]:] == -1)
+                assert np.all(sims[r, n_cand[r]:]
+                              == np.finfo(np.float32).min)
+                assert np.all(idx[r, :n_cand[r]] >= 0)
+        else:
+            # 16 one-tile groups; row r shortlists groups 2r and 2r + 1,
+            # so no two rows of the one 8-row block share a tile.
+            assign = np.repeat(np.arange(16), 100)
+            short = np.arange(16).reshape(8, 2)
+            layout, table, _ = sparse_parity(rng, 128, assign, 16, short,
+                                             ks=(1, 3), block_b=8)
+            rows = [set(t) - {layout.null_tile} for t in table.tolist()]
+            assert all(len(r) == 2 for r in rows)
+            assert len(set().union(*rows)) == 16
 
     def test_sparse_ties_break_on_original_id(self):
         # Two clusters each holding one copy of every (duplicated)

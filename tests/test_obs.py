@@ -438,6 +438,35 @@ class TestDispatchTiers:
                  if labels.get("kernel") == "am_search"]
         assert "B=4,C=5,D=32" in geoms
 
+    def test_sparse_dispatch_names_its_path(self):
+        """The sparse fine pass labels which path served it: the
+        kernel reading tiles from the slab by DMA, or the oracle's XLA
+        gather."""
+        from repro.deploy import hierarchical as hier
+        from repro.kernels import ops
+        rng = np.random.default_rng(9)
+        am = _bipolar(rng, (6, 128))
+        qp = ops.pack_rows(_bipolar(rng, (2, 128)))
+        layout = hier.build_layout(np.asarray(ops.pack_rows(am).T),
+                                   np.array([0, 1] * 3, np.int32), 2)
+        fam = obs.REGISTRY.get("kernel_dispatch_total")
+
+        def served(path):
+            return sum(v for labels, v in fam.series()
+                       if labels.get("kernel") == "am_search_sparse"
+                       and labels["geometry"].endswith(f"path={path}"))
+
+        for use_kernel, path in ((True, "slab-dma"),
+                                 (False, "xla-gather")):
+            before = served(path)
+            ops.am_search_sparse(
+                qp, jnp.asarray(layout.slab), jnp.asarray(layout.col_ids),
+                jnp.zeros((2, 1), jnp.int32),
+                jnp.asarray(layout.tile_start),
+                jnp.asarray(layout.tile_count), n_dims=128, k=1,
+                max_tiles=layout.max_tiles, use_kernel=use_kernel)
+            assert served(path) == before + 1
+
 
 # ------------------------------------------------------------------- logs
 

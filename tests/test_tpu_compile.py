@@ -37,6 +37,13 @@ GEOMETRIES = {"F784_D128_C128": (784, 128, 128),
               "F784_D1024_C1024": (784, 1024, 1024)}
 G, S, MAX_TILES = 448, 8, 4
 C_SLAB = 100_352 + 128 * 64  # C ~ 100k laid out in cluster tiles
+# The served hierarchical shape: 1,024-row batches over a 131,073-label
+# slab of 1,297 cluster tiles plus the null tile, G = 507, S = 8, groups
+# of at most 3 tiles. Its exact configuration (S = G) gives 1,521 tile
+# slots a row: the tile table reaches SMEM a block row at a time, so
+# neither B nor S bounds it.
+SERVED_B, SERVED_G, SERVED_MAX_TILES = 1024, 507, 3
+SERVED_SLAB = 1298 * 128
 
 f32, u8, i32 = jnp.float32, jnp.uint8, jnp.int32
 
@@ -82,6 +89,20 @@ def kernels(f: int, d: int, c: int) -> dict:
                 interpret=False),
             [((B, dp), u8), ((dp, C_SLAB), u8), ((C_SLAB,), i32),
              ((B, S), i32), ((G,), i32), ((G,), i32)])
+        out["am_search_sparse_served"] = (
+            lambda q, a, ids, sl, ts, tc: am_search_sparse(
+                q, a, ids, sl, ts, tc, n_dims=d, k=1,
+                max_tiles=SERVED_MAX_TILES, interpret=False),
+            [((SERVED_B, dp), u8), ((dp, SERVED_SLAB), u8),
+             ((SERVED_SLAB,), i32), ((SERVED_B, S), i32),
+             ((SERVED_G,), i32), ((SERVED_G,), i32)])
+        out["am_search_sparse_exact"] = (
+            lambda q, a, ids, sl, ts, tc: am_search_sparse(
+                q, a, ids, sl, ts, tc, n_dims=d, k=1,
+                max_tiles=SERVED_MAX_TILES, interpret=False),
+            [((B, dp), u8), ((dp, SERVED_SLAB), u8),
+             ((SERVED_SLAB,), i32), ((B, SERVED_G), i32),
+             ((SERVED_G,), i32), ((SERVED_G,), i32)])
     return out
 
 
