@@ -1,6 +1,7 @@
 """The sparse re-rank kernel (the shortlisted groups' members) against its
-roofline. The kernel is the custom-call of ``am_search_sparse_gathered``;
-the gather of the shortlisted tiles before it is XLA work of its own."""
+roofline. The kernel is the custom-call of ``am_search_sparse_gathered``,
+which reads the shortlisted tiles from the slab itself, so its time holds
+the tile reads as well as the popcount search."""
 from bench import layers, work
 
 
