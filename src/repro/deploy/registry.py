@@ -25,8 +25,9 @@ from typing import Callable, Dict, Tuple
 _BACKENDS: Dict[str, Callable] = {}
 
 # Modules whose import registers the built-in backends.
-_BUILTIN_MODULES = ("repro.deploy.digital", "repro.deploy.hierarchical",
-                    "repro.deploy.multibit", "repro.imcsim.deploy")
+_BUILTIN_MODULES = ("repro.deploy.digital", "repro.deploy.frontend",
+                    "repro.deploy.hierarchical", "repro.deploy.multibit",
+                    "repro.imcsim.deploy")
 
 
 def register_backend(name: str) -> Callable[[Callable], Callable]:

@@ -69,8 +69,12 @@ DEFAULT_BLOCK_B = 128
 TUNE_BLOCK_B = (32, 64, 128, 256, 512)
 
 
-def _make_kernel(n_valid_dims: int):
-    """Bind the static valid-dimension count into the kernel body."""
+def _make_kernel(n_valid_dims: int, bf16: bool):
+    """Bind the static valid-dimension count into the kernel body.
+
+    float32 features contract at ``HIGHEST``; bfloat16 features against
+    the bipolar projection (exact in bfloat16) take one MXU pass with
+    float32 accumulation, every product exact."""
 
     def kernel(x_ref, w_ref, o_ref, acc_ref):
         j, k = pl.program_id(1), pl.program_id(2)
@@ -80,12 +84,16 @@ def _make_kernel(n_valid_dims: int):
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        acc_ref[...] += jnp.dot(
-            x_ref[...].astype(jnp.float32),
-            w_ref[...].astype(jnp.float32),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
-        )
+        if bf16:
+            acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
+                                    preferred_element_type=jnp.float32)
+        else:
+            acc_ref[...] += jnp.dot(
+                x_ref[...].astype(jnp.float32),
+                w_ref[...].astype(jnp.float32),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST,
+            )
 
         @pl.when(k == nk - 1)
         def _sign_and_pack():
@@ -107,7 +115,8 @@ def encode_pack(feats: Array, projection: Array, *,
     """Fused encode + sign + bitpack: (B, f) features -> (B, Dp) uint8.
 
     Args:
-      feats: (B, f) float features.
+      feats: (B, f) float features; bfloat16 rows are contracted as
+        bfloat16 (the projection cast to it, exactly), others as float32.
       projection: (f, D) bipolar projection matrix M.
       block_b: batch tile height.
       interpret: force Pallas interpret mode (defaults to True off-TPU).
@@ -123,15 +132,17 @@ def encode_pack(feats: Array, projection: Array, *,
     f2, d = projection.shape
     assert f == f2, (feats.shape, projection.shape)
 
+    bf16 = feats.dtype == jnp.bfloat16
+    dt = jnp.bfloat16 if bf16 else jnp.float32
     bb = min(block_b, max(b, 1))
     td = min(round_up(d, TILE), MAX_TD)
-    xp = pad_tiles(feats.astype(jnp.float32), bb, TILE)
-    wp = pad_tiles(projection.astype(jnp.float32), TILE, td)
+    xp = pad_tiles(feats.astype(dt), bb, TILE)
+    wp = pad_tiles(projection.astype(dt), TILE, td)
     gb, gf, gd = (xp.shape[0] // bb, xp.shape[1] // TILE,
                   wp.shape[1] // td)
 
     out = pl.pallas_call(
-        _make_kernel(d),
+        _make_kernel(d, bf16),
         grid=(gb, gd, gf),
         in_specs=[
             pl.BlockSpec((bb, TILE), lambda i, j, k: (i, k)),

@@ -174,6 +174,9 @@ def serve_batches(deployed, requests: Sequence[Request],
     service_ms: List[float] = []
     rows_real = rows_padded = 0
     inflight: deque = deque()  # (seq, batch, n_valid, result, t_disp)
+    # Artifacts that count their own work from each drained batch's
+    # output rows (the LM front end's counters).
+    record = getattr(deployed, "record_batch", None)
     last_ready = [float("-inf")]  # when the device finished batch k-1
     hist = obs.histogram(
         "serve_batch_ms", "per-batch serving latency by stage")
@@ -201,6 +204,8 @@ def serve_batches(deployed, requests: Sequence[Request],
         hist.observe(queue * 1e3, stage="queue")
         hist.observe((lat - queue) * 1e3, stage="service")
         pred = np.asarray(fut)[:n_valid]
+        if record is not None:
+            record(pred)
         ofs = 0
         for r in batch:
             responses[r.rid] = pred[ofs:ofs + r.size]

@@ -22,6 +22,19 @@ from typing import Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnSpec:
+    """YaRN RoPE extension (arXiv:2309.00071), as DeepSeek-V3 states it:
+    ``rope_scaling`` of its ``config.json``."""
+
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class AttnSpec:
     """Attention mixer settings (GQA or MLA)."""
 
@@ -39,6 +52,7 @@ class AttnSpec:
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
+    rope_scaling: Optional[YarnSpec] = None   # None = plain RoPE
 
     def __post_init__(self):
         if self.kind not in ("gqa", "mla"):
@@ -76,6 +90,19 @@ class FfnSpec:
     d_ff_expert: int = 0
     capacity_factor: float = 1.25
     router: str = "softmax"       # "softmax" | "sigmoid" (dsv3 aux-free)
+    # Group-limited routing (DeepSeek-V3 ``noaux_tc``): experts fall in
+    # ``n_group`` equal groups, each scored by the sum of its top-2
+    # biased scores, and only the ``topk_group`` best groups may be
+    # chosen from. 1 group = no limit.
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    # The expert share this device holds: experts
+    # [held_start, held_start + n_held) of ``n_experts``. 0 = all of
+    # them. A held-range layer routes over all experts, computes only
+    # its own experts' part (plus the shared experts) and drops no slot.
+    n_held: int = 0
+    held_start: int = 0
 
     def __post_init__(self):
         if self.kind not in ("dense", "moe"):
@@ -86,6 +113,22 @@ class FfnSpec:
         if self.kind == "moe" and (self.n_experts <= 0
                                    or self.d_ff_expert <= 0):
             raise ValueError("moe needs n_experts and d_ff_expert")
+        if self.kind == "moe" and self.n_group > 1 and (
+                self.router != "sigmoid" or self.n_experts % self.n_group
+                or not 1 <= self.topk_group <= self.n_group
+                or self.topk_group * (self.n_experts // self.n_group)
+                < self.top_k):
+            raise ValueError("group-limited routing needs the sigmoid "
+                             "router, n_group dividing n_experts, and "
+                             "top_k experts within topk_group groups")
+        if self.n_held and not (0 <= self.held_start and self.held_start
+                                + self.n_held <= self.n_experts):
+            raise ValueError("held expert range outside [0, n_experts)")
+
+    @property
+    def local_experts(self) -> int:
+        """Routed experts whose weights this device holds."""
+        return self.n_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -229,7 +272,7 @@ class ModelConfig:
         else:
             mult = 3  # deepseek experts are glu
             n += d * f.n_experts  # router
-            n += f.n_experts * mult * d * f.d_ff_expert
+            n += f.local_experts * mult * d * f.d_ff_expert
             n += f.n_shared * mult * d * f.d_ff_expert
             if f.router == "sigmoid":
                 n += f.n_experts  # aux-free bias
@@ -246,7 +289,7 @@ class ModelConfig:
             f = b.ffn
             if f.kind == "moe":
                 mult = 3
-                routed_all = f.n_experts * mult * d * f.d_ff_expert
+                routed_all = f.local_experts * mult * d * f.d_ff_expert
                 routed_active = f.top_k * mult * d * f.d_ff_expert
                 full = full - routed_all + routed_active
             total += b.repeat * full

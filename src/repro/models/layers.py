@@ -16,7 +16,10 @@ Attention comes in three executions:
 
 MoE uses sort-based dropping dispatch (argsort by expert, capacity clamp,
 batched expert einsum, scatter-add combine) — the standard TPU-friendly
-formulation that shards experts over the "model" axis (EP).
+formulation that shards experts over the "model" axis (EP). A layer told
+its expert share (one EP rank's experts, ``FfnSpec.n_held``) is dropless
+instead: its routed slots, sorted by expert, run as grouped matmuls
+(``kernels/moe_gmm.py``).
 
 Mamba-2 is the chunked SSD algorithm (arXiv:2405.21060) with a
 constant-memory decode step.
@@ -29,7 +32,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.config import AttnSpec, FfnSpec, SsmSpec
+from repro.models.config import AttnSpec, FfnSpec, SsmSpec, YarnSpec
 from repro.models.sharding import shard_act
 
 Array = jax.Array
@@ -99,16 +102,57 @@ def _dense_init(key: Array, shape, dtype, in_axis: int = 0) -> Array:
     return _maybe_sds(make, shape, dtype)
 
 
-def rope(x: Array, positions: Array, theta: float) -> Array:
-    """Rotary embedding. x: (B, S, H, Dh); positions: (B, S) or (S,)."""
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature term: 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_freqs(dim: int, theta: float, scaling: Optional[YarnSpec] = None,
+               ) -> Array:
+    """Inverse frequencies of the dim/2 rotary pairs.
+
+    With YaRN ``scaling`` (arXiv:2309.00071, as DeepSeek-V3 applies it)
+    the pairs that turn fewer than ``beta_slow`` times over the original
+    context are interpolated (frequency / factor), those that turn more
+    than ``beta_fast`` times are kept, and a linear ramp over the pair
+    index blends the two between.
+    """
+    half = dim // 2
+    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if scaling is None:
+        return freq
+
+    def pair_at(rotations):  # the pair index that turns `rotations` times
+        return (dim * math.log(scaling.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair_at(scaling.beta_fast)), 0)
+    high = min(math.ceil(pair_at(scaling.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return freq / scaling.factor * ramp + freq * (1.0 - ramp)
+
+
+def rope(x: Array, positions: Array, theta: float,
+         scaling: Optional[YarnSpec] = None) -> Array:
+    """Rotary embedding. x: (B, S, H, Dh); positions: (B, S) or (S,).
+
+    Pairs are (i, i + Dh/2), the half-split layout."""
     dh = x.shape[-1]
     half = dh // 2
-    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    freq = rope_freqs(dh, theta, scaling)
     if positions.ndim == 1:
         positions = positions[None, :]
     ang = positions[..., None].astype(jnp.float32) * freq  # (B, S, half)
-    cos = jnp.cos(ang)[:, :, None, :]
-    sin = jnp.sin(ang)[:, :, None, :]
+    # YaRN scales cos/sin by mscale / mscale_all_dim (1 for DeepSeek-V3).
+    amp = 1.0 if scaling is None else (
+        yarn_mscale(scaling.factor, scaling.mscale)
+        / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+    cos = (amp * jnp.cos(ang))[:, :, None, :]
+    sin = (amp * jnp.sin(ang))[:, :, None, :]
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
@@ -173,17 +217,32 @@ def _repeat_kv(k: Array, groups: int) -> Array:
 
 def attention_full(q: Array, k: Array, v: Array, *, q_offset: int = 0,
                    softcap: Optional[float] = None,
-                   chunk: int = 1024) -> Array:
+                   chunk: int = 1024, q_block: Optional[int] = None,
+                   scale: Optional[float] = None) -> Array:
     """Chunked causal attention with online softmax.
 
     q: (B, Sq, H, Dh); k, v: (B, Skv, H, Dh) (kv already head-repeated).
     ``q_offset``: absolute position of q[0] relative to k[0] (prefill
-    continuation); causal mask is (q_offset + i) >= j.
+    continuation); causal mask is (q_offset + i) >= j. ``scale``
+    defaults to 1/sqrt(Dh). With ``q_block`` the queries are taken
+    ``q_block`` at a time, each block against only the keys it may see,
+    so that a score block is (B, H, q_block, chunk) and the key chunks
+    past the diagonal are skipped.
     """
     b, sq, h, dh = q.shape
     skv = k.shape[1]
+    if q_block is not None and sq > q_block:
+        outs = []
+        for start in range(0, sq, q_block):
+            stop = min(start + q_block, sq)
+            seen = min(skv, q_offset + stop)
+            outs.append(attention_full(
+                q[:, start:stop], k[:, :seen], v[:, :seen],
+                q_offset=q_offset + start, softcap=softcap, chunk=chunk,
+                scale=scale))
+        return jnp.concatenate(outs, axis=1)
     dv = v.shape[-1]  # may differ from dh (MLA)
-    scale = 1.0 / math.sqrt(dh)
+    scale = 1.0 / math.sqrt(dh) if scale is None else scale
     chunk = min(chunk, skv)
     n_chunks = -(-skv // chunk)
     pad = n_chunks * chunk - skv
@@ -198,7 +257,8 @@ def attention_full(q: Array, k: Array, v: Array, *, q_offset: int = 0,
         m, l, acc = carry
         kb, vb, cidx = inputs
         k_pos = cidx * chunk + jnp.arange(chunk)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, kb) * scale
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, kb,
+                       preferred_element_type=jnp.float32) * scale
         s = _softcap(s, softcap)
         mask = (q_pos[:, None] >= k_pos[None, :]) & (
             k_pos[None, :] < skv)
@@ -588,8 +648,26 @@ def _mla_q(p: Params, spec: AttnSpec, x: Array, positions: Array,
     else:
         q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q_nope = q[..., : spec.qk_nope_dim]
-    q_rope = rope(q[..., spec.qk_nope_dim:], positions, spec.rope_theta)
+    q_rope = rope(q[..., spec.qk_nope_dim:], positions, spec.rope_theta,
+                  spec.rope_scaling)
     return q_nope, q_rope
+
+
+def mla_scale(spec: AttnSpec) -> float:
+    """Softmax scale: 1/sqrt(qk head dim), times YaRN's mscale squared
+    where the RoPE is YaRN-extended (DeepSeek-V3: mscale_all_dim 1,
+    factor 40 -> 1.369^2)."""
+    scale = 1.0 / math.sqrt(spec.qk_nope_dim + spec.qk_rope_dim)
+    y = spec.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
+# Prefill attention takes its queries at least this many at a time, in
+# at most 8 blocks (so that long training sequences unroll no further):
+# a (B, H, 512, 512) float32 score block of 128 heads at B = 4 is 0.5 GB.
+MLA_Q_BLOCK = 512
 
 
 def mla_forward(p: Params, spec: AttnSpec, x: Array, positions: Array,
@@ -599,7 +677,7 @@ def mla_forward(p: Params, spec: AttnSpec, x: Array, positions: Array,
     kv = x @ p["wkv_a"]  # (B, S, lora + rope)
     c_kv = rms_norm(kv[..., : spec.kv_lora_rank], p["kv_norm"], eps)
     k_rope = rope(kv[..., spec.kv_lora_rank:][:, :, None, :], positions,
-                  spec.rope_theta)  # (B, S, 1, rope)
+                  spec.rope_theta, spec.rope_scaling)  # (B, S, 1, rope)
     k_nope = jnp.einsum("bsr,rhk->bshk", c_kv, p["wk_b"])
     v = jnp.einsum("bsr,rhk->bshk", c_kv, p["wv_b"])
     h = spec.n_heads
@@ -607,7 +685,10 @@ def mla_forward(p: Params, spec: AttnSpec, x: Array, positions: Array,
         [k_nope, jnp.broadcast_to(k_rope, k_nope.shape[:-1]
                                   + (spec.qk_rope_dim,))], axis=-1)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    out = attention_full(q, k, v)  # v head dim differs from qk dim — ok
+    # v head dim differs from qk dim — ok
+    q_block = max(MLA_Q_BLOCK, -(-x.shape[1] // 8))
+    out = attention_full(q, k, v, chunk=MLA_Q_BLOCK, q_block=q_block,
+                         scale=mla_scale(spec))
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
 
 
@@ -626,7 +707,7 @@ def mla_decode(p: Params, spec: AttnSpec, x: Array, cache: Dict[str, Array],
     kv = x @ p["wkv_a"]
     c_new = rms_norm(kv[..., : spec.kv_lora_rank], p["kv_norm"], eps)
     kr_new = rope(kv[..., spec.kv_lora_rank:][:, :, None, :], pos[:, None],
-                  spec.rope_theta)[:, :, 0, :]
+                  spec.rope_theta, spec.rope_scaling)[:, :, 0, :]
 
     bidx = jnp.arange(b)
     ckv = cache["ckv"].at[bidx, pos].set(c_new[:, 0])
@@ -634,7 +715,7 @@ def mla_decode(p: Params, spec: AttnSpec, x: Array, cache: Dict[str, Array],
 
     # Absorb W_UK into q: (B,1,H,nope) x (lora,H,nope) -> (B,1,H,lora)
     q_abs = jnp.einsum("bshk,rhk->bshr", q_nope, p["wk_b"])
-    scale = 1.0 / math.sqrt(spec.qk_nope_dim + spec.qk_rope_dim)
+    scale = mla_scale(spec)
     s = (jnp.einsum("bshr,btr->bhst", q_abs, ckv)
          + jnp.einsum("bshk,btk->bhst", q_rope, krope)) * scale
     k_pos = jnp.arange(ckv.shape[1])
@@ -733,11 +814,12 @@ def init_moe_ffn(key: Array, d_model: int, spec: FfnSpec, dtype,
                  ) -> Tuple[Params, Axes]:
     ks = jax.random.split(key, 7)
     e, f = spec.n_experts, spec.d_ff_expert
+    el = spec.local_experts  # the router spans all e; weights are local
     p: Params = {
         "router": _dense_init(ks[0], (d_model, e), jnp.float32),
-        "w_gate": _dense_init(ks[1], (e, d_model, f), dtype),
-        "w_up": _dense_init(ks[2], (e, d_model, f), dtype),
-        "w_down": _dense_init(ks[3], (e, f, d_model), dtype),
+        "w_gate": _dense_init(ks[1], (el, d_model, f), dtype),
+        "w_up": _dense_init(ks[2], (el, d_model, f), dtype),
+        "w_down": _dense_init(ks[3], (el, f, d_model), dtype),
     }
     a: Axes = {
         "router": ("embed", None),
@@ -773,8 +855,11 @@ def moe_ffn(p: Params, spec: FfnSpec, x: Array,
 
     aux carries the load-balance loss (softmax router) or the per-expert
     token counts (sigmoid router — the train loop applies DeepSeek-V3's
-    aux-free bias update with them).
+    aux-free bias update with them). A layer told its expert share
+    (``spec.n_held``) runs ``_moe_ffn_held`` instead.
     """
+    if spec.n_held:
+        return _moe_ffn_held(p, spec, x)
     from repro.models import sharding as sh_mod
     rules = sh_mod.current_rules()
     if rules is not None and "model" in rules.mesh.axis_names:
@@ -790,17 +875,8 @@ def _moe_ffn_local(p: Params, spec: FfnSpec, x: Array,
     t = b * s
     xt = x.reshape(t, d)
 
-    logits = (xt.astype(jnp.float32) @ p["router"])  # (T, E)
-    if spec.router == "sigmoid":
-        scores = jax.nn.sigmoid(logits)
-        sel_scores = scores + p["router_bias"]  # bias only affects choice
-        _, top_i = jax.lax.top_k(sel_scores, k)
-        top_w = jnp.take_along_axis(scores, top_i, axis=1)
-        top_w = top_w / (top_w.sum(axis=-1, keepdims=True) + 1e-20)
-    else:
-        scores = jax.nn.softmax(logits, axis=-1)
-        top_w, top_i = jax.lax.top_k(scores, k)
-        top_w = top_w / (top_w.sum(axis=-1, keepdims=True) + 1e-20)
+    scores, top_w, top_i = _route(_router_logits(xt, p["router"]), spec,
+                                  p.get("router_bias"))
 
     # ---- sort-based dispatch ------------------------------------------------
     # Small token counts (decode steps, smoke tests) get worst-case
@@ -854,19 +930,116 @@ def _moe_ffn_local(p: Params, spec: FfnSpec, x: Array,
     return y.reshape(b, s, d), aux
 
 
+def _router_logits(xt: Array, router: Array) -> Array:
+    """(T, E) router logits in float32, as DeepSeek-V3's gate computes
+    them (a bf16 product would move near-tied expert choices)."""
+    return jnp.dot(xt.astype(jnp.float32), router,
+                   precision=jax.lax.Precision.HIGHEST)
+
+
 def _route(logits: Array, spec: FfnSpec, router_bias: Optional[Array],
            ) -> Tuple[Array, Array, Array]:
-    """(scores, top_w, top_i) for either router flavour."""
+    """(scores, top_w, top_i) for either router flavour.
+
+    Sigmoid (DeepSeek-V3 ``noaux_tc``): scores are sigmoid(logits); the
+    choice uses scores + bias; with ``n_group`` > 1 each group of
+    experts is scored by the sum of its two best biased scores and only
+    the ``topk_group`` best groups stay eligible; the weights are the
+    unbiased scores of the chosen experts, normalised. Both flavours
+    scale the weights by ``routed_scaling_factor``.
+    """
     if spec.router == "sigmoid":
         scores = jax.nn.sigmoid(logits)
         sel = scores + (router_bias if router_bias is not None else 0.0)
+        if spec.n_group > 1:
+            t, e = sel.shape
+            grouped = sel.reshape(t, spec.n_group, e // spec.n_group)
+            group_score = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)
+            _, keep = jax.lax.top_k(group_score, spec.topk_group)
+            kept = jnp.any(keep[:, :, None] == jnp.arange(spec.n_group),
+                           axis=1)                          # (T, n_group)
+            sel = jnp.where(jnp.repeat(kept, e // spec.n_group, axis=1),
+                            sel, -jnp.inf)
         _, top_i = jax.lax.top_k(sel, spec.top_k)
         top_w = jnp.take_along_axis(scores, top_i, axis=1)
     else:
         scores = jax.nn.softmax(logits, axis=-1)
         top_w, top_i = jax.lax.top_k(scores, spec.top_k)
     top_w = top_w / (top_w.sum(axis=-1, keepdims=True) + 1e-20)
-    return scores, top_w, top_i
+    return scores, top_w * spec.routed_scaling_factor, top_i
+
+
+# Held slots a pass of the held-range MoE layer computes: about twice a
+# batch's expected load at 8,192 tokens (8 of 256 experts x top-8: 2,048).
+MOE_CHUNK = 4096
+
+
+def _moe_ffn_held(p: Params, spec: FfnSpec, x: Array,
+                  ) -> Tuple[Array, Dict[str, Array]]:
+    """Expert-parallel MoE on one device's share: experts
+    [held_start, held_start + n_held) of ``n_experts``, dropless.
+
+    Routes every token over all experts, then computes only the held
+    experts' part of the result plus the shared experts. The slots
+    routed to held experts, sorted by expert, run ``MOE_CHUNK`` at a time
+    as grouped matmuls (``kernels.moe_gmm``), pass after pass until every
+    held slot is done, so no slot is dropped, no expert is padded to a
+    capacity and the buffers stay one pass's size. What the other
+    devices' experts add is left out (the EP exchange is not run).
+
+    aux: ``held_counts`` (B, n_held) int32, each row's slots routed to
+    each held expert; ``dropped`` (B,) int32, each row's held slots that
+    no pass computed (0 by construction).
+    """
+    from repro.kernels.moe_gmm import TM, moe_gmm
+    b, s, d = x.shape
+    t, k, nh = b * s, spec.top_k, spec.n_held
+    xt = x.reshape(t, d)
+    _, top_w, top_i = _route(_router_logits(xt, p["router"]), spec,
+                             p.get("router_bias"))
+    local = top_i - spec.held_start
+    held = (local >= 0) & (local < nh)                     # (T, k)
+    key = jnp.where(held, local, nh).reshape(-1)           # (T*k,)
+    order = jnp.argsort(key, stable=True)                  # held first
+    sizes = jnp.zeros((nh,), jnp.int32).at[key].add(1, mode="drop")
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                               jnp.cumsum(sizes)])         # (nh + 1,)
+    n_slots = offsets[nh]
+    chunk = min(MOE_CHUNK, -(-t * k // TM) * TM)
+    tok = jnp.pad(order // k, (0, chunk))                  # slot -> token
+    w = jnp.pad(top_w.reshape(-1)[order], (0, chunk))
+
+    def one_pass(c, y):
+        start = c * chunk
+        rows = jax.lax.dynamic_slice(tok, (start,), (chunk,))
+        ends = jnp.clip(offsets, start, start + chunk) - start
+        # The held experts' rows of this pass, then the rest, skipped.
+        groups = jnp.append(jnp.diff(ends), chunk - ends[nh])
+        h = moe_gmm(xt[rows], p["w_gate"], groups, p["w_up"])
+        out = moe_gmm(h, p["w_down"], groups)               # (chunk, D)
+        mine = jnp.arange(chunk) < ends[nh]   # other rows are unwritten
+        wc = jax.lax.dynamic_slice(w, (start,), (chunk,))
+        return y.at[rows].add(jnp.where(
+            mine[:, None], out.astype(jnp.float32) * wc[:, None], 0.0))
+
+    passes = -(-n_slots // chunk)
+    y = jax.lax.fori_loop(0, passes, one_pass,
+                          jnp.zeros((t, d), jnp.float32))
+    if spec.n_shared:
+        sh = jax.nn.silu(xt @ p["ws_gate"]) * (xt @ p["ws_up"])
+        y = y + (sh @ p["ws_down"]).astype(jnp.float32)
+
+    # A slot's place in the sorted order; held slots past the passes run
+    # would be dropped.
+    pos = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32)).reshape(t, k)
+    dropped = held & (pos >= passes * chunk)
+    per_row = jax.nn.one_hot(key.reshape(b, s * k), nh + 1,
+                             dtype=jnp.int32).sum(axis=1)
+    aux = {"held_counts": per_row[:, :nh],
+           "dropped": jnp.sum(dropped.reshape(b, s * k), axis=1,
+                              dtype=jnp.int32)}
+    return y.astype(x.dtype).reshape(b, s, d), aux
 
 
 def _moe_ffn_sharded(p: Params, spec: FfnSpec, x: Array, rules,
@@ -919,9 +1092,9 @@ def _moe_ffn_sharded(p: Params, spec: FfnSpec, x: Array, rules,
 
     def local_fn(xt_l, router, bias, wg, wu, wd):
         # xt_l: (t_local, d); wg/wu/wd: (e_local, ..., ...)
-        logits = xt_l.astype(jnp.float32) @ router
         scores, top_w, top_i = _route(
-            logits, spec, bias[0] if bias is not None else None)
+            _router_logits(xt_l, router), spec,
+            bias[0] if bias is not None else None)
 
         flat_e = top_i.reshape(-1)                      # (t_local*k,)
         order = jnp.argsort(flat_e)

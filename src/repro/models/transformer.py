@@ -13,6 +13,7 @@ Public surface:
   loss_fn(params, cfg, batch)            -> scalar loss, metrics
   init_cache(cfg, batch, max_len, dtype) -> decode caches
   decode_step(params, cfg, batch, cache) -> logits, cache
+  pooled_features(params, cfg, tokens)   -> pooled hidden states, aux
 """
 from __future__ import annotations
 
@@ -187,6 +188,7 @@ def _layer_forward(cfg: ModelConfig, b: BlockSpec, lp: Params, x: Array,
 def _group_forward(cfg: ModelConfig, b: BlockSpec, gp: Params, x: Array,
                    positions: Array, cond: Optional[Array],
                    ) -> Tuple[Array, Dict[str, Array]]:
+    """Scan the group's layers; aux entries stacked per layer."""
     def body(carry, lp):
         y, aux = _layer_forward(cfg, b, lp, carry, positions, cond)
         return y, aux
@@ -194,10 +196,7 @@ def _group_forward(cfg: ModelConfig, b: BlockSpec, gp: Params, x: Array,
     if cfg.remat:
         body = jax.checkpoint(
             body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    x, auxs = jax.lax.scan(body, x, gp)
-    # Sum per-layer aux across the group.
-    aux = {k: jnp.sum(v, axis=0) for k, v in auxs.items()} if auxs else {}
-    return x, aux
+    return jax.lax.scan(body, x, gp)
 
 
 def embed_inputs(params: Params, cfg: ModelConfig, batch: Dict[str, Array],
@@ -242,7 +241,9 @@ def forward(params: Params, cfg: ModelConfig, batch: Dict[str, Array],
     x = shard_act(x, ("batch", "seq", "act_embed"))
     aux_total: Dict[str, Array] = {}
     for gi, (b, gp) in enumerate(zip(cfg.blocks, params["groups"])):
-        x, aux = _group_forward(cfg, b, gp, x, positions, cond)
+        x, auxs = _group_forward(cfg, b, gp, x, positions, cond)
+        # Sum per-layer aux across the group.
+        aux = {k: jnp.sum(v, axis=0) for k, v in auxs.items()}
         for k, v in aux.items():
             if k == "expert_counts":
                 # Kept per group (groups may differ in expert count) for
@@ -255,6 +256,35 @@ def forward(params: Params, cfg: ModelConfig, batch: Dict[str, Array],
     logits = _head(params, cfg, h)
     aux_total["final_hidden"] = h
     return logits, aux_total
+
+
+def pooled_features(params: Params, cfg: ModelConfig, tokens: Array,
+                    ) -> Tuple[Array, Dict[str, Array]]:
+    """Prefill as a frozen feature extractor: embed the (B, S) token
+    rows, run the layer groups, apply the final RMSNorm and take each
+    row's mean over its positions. No output head, no MTP module.
+
+    Returns ((B, d_model) pooled hidden states in the activation dtype
+    (bfloat16 for a bfloat16 model), aux): for
+    held-range MoE layers (``FfnSpec.n_held``) aux holds
+    ``held_counts`` (B, MoE layers, n_held) int32, the row's slots
+    routed to each held expert in each layer, and ``dropped`` (B,)
+    int32, the row's held slots left uncomputed over all layers.
+    """
+    x, positions, _ = embed_inputs(params, cfg, {"tokens": tokens})
+    counts, dropped = [], []
+    for b, gp in zip(cfg.blocks, params["groups"]):
+        x, auxs = _group_forward(cfg, b, gp, x, positions, None)
+        if "held_counts" in auxs:              # (layers, B, n_held)
+            counts.append(jnp.swapaxes(auxs["held_counts"], 0, 1))
+            dropped.append(jnp.sum(auxs["dropped"], axis=0))
+    h = L.rms_norm(x, params["ln_f"], cfg.rms_eps)
+    pooled = jnp.mean(h.astype(jnp.float32), axis=1).astype(h.dtype)
+    aux = {}
+    if counts:
+        aux = {"held_counts": jnp.concatenate(counts, axis=1),
+               "dropped": sum(dropped)}
+    return pooled, aux
 
 
 # ---------------------------------------------------------------------------

@@ -9,7 +9,9 @@ from repro.deploy import (DeployedArtifact, available_backends, deploy,
                           get_backend, register_backend)
 from repro.deploy.base import pytree_artifact
 
-BACKENDS = sorted(available_backends())
+# The frontend backend serves token rows through a frozen LM in front of
+# the packed AM, not feature rows; tests/test_dsv3_frontend.py checks it.
+BACKENDS = sorted(t for t in available_backends() if t != "frontend")
 # The multibit backend reads out against the QUANTIZED float shadow,
 # not the binary AM — bit-exact parity with model.predict is the wrong
 # contract for it (its oracle parity lives in TestMultibitBackend).
@@ -33,6 +35,7 @@ class TestRegistry:
     def test_builtin_backends_registered(self):
         assert {"packed", "unpacked", "imc", "hierarchical",
                 "multibit"} <= set(BACKENDS)
+        assert "frontend" in available_backends()
 
     def test_unknown_target_error_names_backends(self, trained):
         _, m = trained

@@ -26,6 +26,7 @@ from repro.kernels.am_search_packed import am_search_packed
 from repro.kernels.am_search_sparse import am_search_sparse
 from repro.kernels.am_shortlist import am_shortlist
 from repro.kernels.encode_fused import encode_pack
+from repro.kernels.moe_gmm import moe_gmm
 from repro.kernels.pack_bits import pack_bits
 from repro.kernels.qail_update import qail_update
 
@@ -45,7 +46,7 @@ C_SLAB = 100_352 + 128 * 64  # C ~ 100k laid out in cluster tiles
 SERVED_B, SERVED_G, SERVED_MAX_TILES = 1024, 507, 3
 SERVED_SLAB = 1298 * 128
 
-f32, u8, i32 = jnp.float32, jnp.uint8, jnp.int32
+f32, u8, i32, bf16 = jnp.float32, jnp.uint8, jnp.int32, jnp.bfloat16
 
 
 def kernels(f: int, d: int, c: int) -> dict:
@@ -144,6 +145,32 @@ def no_persistent_cache():
 def test_kernel_compiles_for_v5e(geometry, name, one_chip,
                                  no_persistent_cache):
     fn, operands = kernels(*GEOMETRIES[geometry])[name]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in operands]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# The DeepSeek-V3 front end's kernels at its widths: 4 pooled bf16 rows of
+# 7,168 features into D = 1,024, and the held experts' grouped matmuls
+# over one pass of 4,096 routed slots (8 held experts, d_ff 2,048).
+FRONTEND = {
+    "encode_pack_bf16": (lambda x, m: encode_pack(x, m, interpret=False),
+                         [((4, 7168), bf16), ((7168, 1024), bf16)]),
+    "moe_gmm_gate_up": (
+        lambda a, w, g, u: moe_gmm(a, w, g, u, interpret=False),
+        [((4096, 7168), bf16), ((8, 7168, 2048), bf16), ((9,), i32),
+         ((8, 7168, 2048), bf16)]),
+    "moe_gmm_down": (lambda a, w, g: moe_gmm(a, w, g, interpret=False),
+                     [((4096, 2048), bf16), ((8, 2048, 7168), bf16),
+                      ((9,), i32)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FRONTEND))
+def test_frontend_kernel_compiles_for_v5e(name, one_chip,
+                                          no_persistent_cache):
+    fn, operands = FRONTEND[name]
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
             for shape, dtype in operands]
     compiled = jax.jit(fn).lower(*args).compile()
